@@ -11,7 +11,9 @@ tenant mix that is a p99 killer.
 
 This benchmark serves the same tenant mix (odd-shaped multiply / gram /
 transpose / add plus a 3-stage fused multiply chain — every shape off
-the bucket grid) against two engines sharing one persistent cache dir:
+the bucket grid) against two engines sharing one persistent cache dir,
+:data:`CACHE_DIR` (a fixed subdirectory of the one compile-cache
+directory, ``compilecache.cache_dir()``), emptied before the cold phase:
 
 * **cold** — a fresh engine, bucketing on, empty cache: each first call
   eats its own trace+compile (recorded in the executable index);
@@ -37,15 +39,15 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import shutil
 import subprocess
 import sys
-import tempfile
 import time
 
 import numpy as np
 
 from benchmarks.common import header, row
-from repro.core import AlchemistContext, AlchemistEngine
+from repro.core import AlchemistContext, AlchemistEngine, compilecache
 from repro.core.engine import make_engine_mesh
 from repro.core.libraries import elemental
 
@@ -71,12 +73,26 @@ ARRAYS = {(routine, name): RNG.randn(*shape).astype(np.float32)
           for routine, shapes in MIX for name, shape in shapes.items()}
 CHAIN_ARRAY = (RNG.randn(*CHAIN_SHAPE) / 4.0).astype(np.float32)
 
+#: this benchmark's own cache: fixed (the path is part of JAX's cache
+#: key), and its own, because the cold phase needs it empty
+CACHE_DIR = os.path.join(compilecache.cache_dir(), "compile_warmup")
 
-def _fresh(cache_dir: str) -> AlchemistContext:
+
+def _use_cache() -> None:
+    """Turn JAX's persistent cache on at :data:`CACHE_DIR`, through the
+    variable every entry point resolves its cache from."""
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = CACHE_DIR
+    compilecache.enable_persistent_cache()
+
+
+def _empty_cache() -> None:
+    shutil.rmtree(CACHE_DIR, ignore_errors=True)
+
+
+def _fresh() -> AlchemistContext:
     # result cache off: this benchmark prices compiles, not memoization
     engine = AlchemistEngine(make_engine_mesh(1), cache_entries=0,
-                             bucketing=True, bucket_grid=GRID,
-                             compile_cache_dir=cache_dir)
+                             bucketing=True, bucket_grid=GRID)
     engine.load_library("elemental", elemental)
     return AlchemistContext(engine=engine)
 
@@ -104,10 +120,10 @@ def _first_calls(ac: AlchemistContext) -> dict[str, float]:
     return latencies
 
 
-def _serve(cache_dir: str, warm: bool) -> dict:
-    """One engine lifetime against ``cache_dir``: optionally warm up,
+def _serve(warm: bool) -> dict:
+    """One engine lifetime against :data:`CACHE_DIR`: optionally warm up,
     then serve the tenant mix; returns latencies + compile accounting."""
-    ac = _fresh(cache_dir)
+    ac = _fresh()
     engine = ac.engine
     try:
         warmup = engine.warmup(grid=GRID) if warm else None
@@ -122,9 +138,10 @@ def _serve(cache_dir: str, warm: bool) -> dict:
 
 def run(smoke: bool = False, json_path: str | None = None) -> dict:
     header("compile warmup: cold vs warm-restart first-call latency")
-    with tempfile.TemporaryDirectory(prefix="alchemist-ccache-") as cdir:
-        cold = _serve(cdir, warm=False)
-        warm = _serve(cdir, warm=True)
+    _empty_cache()
+    _use_cache()
+    cold = _serve(warm=False)
+    warm = _serve(warm=True)
 
     cold_total = sum(cold["latencies"].values())
     warm_total = sum(warm["latencies"].values())
@@ -185,9 +202,10 @@ def run(smoke: bool = False, json_path: str | None = None) -> dict:
 # ---------------------------------------------------------------------------
 # two-process persistence round trip (the restart story, for real)
 # ---------------------------------------------------------------------------
-def _phase(cache_dir: str, warm: bool) -> None:
+def _phase(warm: bool) -> None:
     """Subprocess body: one engine lifetime, printing its accounting."""
-    out = _serve(cache_dir, warm=warm)
+    _use_cache()
+    out = _serve(warm=warm)
     summary = {
         "request_compiles": out["compile_stats"]["request_compiles"],
         "bucketed_request_compiles":
@@ -214,10 +232,9 @@ def run_two_process() -> dict:
         [os.path.join(root, "src"), root,
          env.get("PYTHONPATH", "")]).rstrip(os.pathsep)
 
-    def spawn(phase: str, cdir: str) -> dict:
+    def spawn(phase: str) -> dict:
         proc = subprocess.run(
-            [sys.executable, os.path.abspath(__file__),
-             f"--{phase}", cdir],
+            [sys.executable, os.path.abspath(__file__), f"--{phase}"],
             capture_output=True, text=True, env=env, cwd=root,
             timeout=600)
         if proc.returncode != 0:
@@ -229,9 +246,10 @@ def run_two_process() -> dict:
         raise RuntimeError(f"{phase} printed no PHASE_RESULT:\n"
                            f"{proc.stdout}")
 
-    with tempfile.TemporaryDirectory(prefix="alchemist-ccache2p-") as cdir:
-        first = spawn("persist-phase1", cdir)
-        second = spawn("persist-phase2", cdir)
+    # the parent never touches a device: each child holds it in turn
+    _empty_cache()
+    first = spawn("persist-phase1")
+    second = spawn("persist-phase2")
     row("two_process_cold_total", first["total_first_call_s"] * 1e6)
     row("two_process_warm_total", second["total_first_call_s"] * 1e6,
         f"replayed={second['replayed']}")
@@ -250,16 +268,16 @@ def main() -> None:
                     help="write machine-readable results to PATH")
     ap.add_argument("--two-process", action="store_true",
                     help="run the cross-process persistence round trip")
-    ap.add_argument("--persist-phase1", metavar="DIR",
+    ap.add_argument("--persist-phase1", action="store_true",
                     help=argparse.SUPPRESS)      # subprocess entry
-    ap.add_argument("--persist-phase2", metavar="DIR",
+    ap.add_argument("--persist-phase2", action="store_true",
                     help=argparse.SUPPRESS)      # subprocess entry
     args = ap.parse_args()
     if args.persist_phase1:
-        _phase(args.persist_phase1, warm=False)
+        _phase(warm=False)
         return
     if args.persist_phase2:
-        _phase(args.persist_phase2, warm=True)
+        _phase(warm=True)
         return
     if args.two_process:
         run_two_process()

@@ -20,6 +20,7 @@ from benchmarks import (
     table4_cg_features,
     table5_svd,
 )
+from repro.core import compilecache
 
 ALL = {
     "table2": table2_cg.run,
@@ -55,6 +56,7 @@ ALL = {
 
 def main() -> None:
     which = sys.argv[1:] or list(ALL)
+    compilecache.enable_persistent_cache()
     print("name,us_per_call,derived")
     for name in which:
         ALL[name]()

@@ -13,7 +13,7 @@ exposes it against that registry (the FRAME_SPECS pattern):
   keyword parameter, and accept nothing that is not an option — the
   typed client surface is exactly the registry.
 * the server CLI must define every flag an option declares
-  (``--compile-cache-dir``, ``--warmup``, ``--no-bucketing``).
+  (``--warmup``, ``--no-bucketing``).
 
 Parameterizable for the violating-fixture tests: pass ``options`` and
 any of the four paths to point the rule at crafted inputs.

@@ -178,10 +178,19 @@ class ExecutionPlan:
     ``input_specs`` maps each :class:`Input` slot to its operand's
     ``(shape, dtype)`` — filled by the engine from the arrays it
     actually materialized (post-bucketing, when bucketing applies).
+    ``input_layouts`` names each operand's engine layout (part of the
+    program's identity: the same shapes row-blocked over four devices
+    and replicated are different programs), and ``input_shardings`` is
+    the device sharding that layout realizes on the engine's mesh — what
+    an AOT compile lowers against.
     """
     steps: list[PlanStep]
     # slot -> (shape tuple, dtype string); None = shapes unknown
     input_specs: Optional[dict[str, tuple[tuple, str]]] = None
+    # slot -> layout tag (handles.LAYOUTS); None = unplaced
+    input_layouts: Optional[dict[str, str]] = None
+    # slot -> jax Sharding realizing the layout; derived, not keyed
+    input_shardings: Optional[dict[str, Any]] = None
 
     def signature(self) -> Optional[tuple]:
         """Hashable key for compile caching: per step the routine
@@ -204,8 +213,10 @@ class ExecutionPlan:
                 return None
         specs = None
         if self.input_specs is not None:
+            layouts = self.input_layouts or {}
             specs = tuple(sorted(
-                (slot, tuple(int(d) for d in shape), str(dtype))
+                (slot, tuple(int(d) for d in shape), str(dtype),
+                 layouts.get(slot))
                 for slot, (shape, dtype) in self.input_specs.items()))
         return (tuple(sig), specs)
 

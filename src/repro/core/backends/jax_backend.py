@@ -5,9 +5,18 @@ All of the bundled libraries' compute moved here from
 ``core/libraries/*.py`` (the library modules now carry only the typed
 specs). Implementations are array-level: jax arrays in, jax arrays out;
 the blocked Pallas kernels under ``src/repro/kernels`` are reused where
-they exist (``gram``, ``rf_map``, ``normal_matvec`` — all with jnp
-fallbacks on this CPU container, Pallas interpret-mode validated by the
-kernel test sweeps).
+they exist (``gram``, ``rf_map``, ``normal_matvec``, each with a jnp
+fallback). The platform decides how a requested kernel runs: compiled
+on a TPU, interpreted on the CPU (``kernels.interpret_mode``).
+
+**Precision.** Every routine registered here traces its matmuls at full
+float32 precision (``jax.default_matmul_precision("highest")``, scoped
+to the routine's own trace, never process-wide). At the default
+precision, on a v5e at the paper's widths, the CG solve's true residual
+stalled at 7e-3 instead of 2e-5 and a Gram matrix came 5e-4 off the
+float64 one; the Lanczos matvec read the same at both. A faster
+precision is a per-routine choice to be judged against the float64
+reference.
 
 **Chain fusion.** Implementations marked ``fusible`` are pure, traceable
 array programs. When the engine drains a dependency chain of deferred
@@ -21,13 +30,15 @@ end. Compiled programs are cached by plan structure
 (:meth:`ExecutionPlan.signature`), so a tenant replaying the same chain
 shape pays tracing once.
 
-Host-loop drivers (Lanczos SVD, CG, NMF) are registered non-fusible:
+Host-loop drivers (Lanczos SVD, CG, NMF, and ``gram_svd`` with its host
+eigensolve) are registered non-fusible:
 they are reverse-communication loops around jitted matvecs, exactly like
 ARPACK driving distributed matvecs in the paper's MPI implementation.
 """
 from __future__ import annotations
 
 import collections
+import functools
 import time
 
 import jax
@@ -175,8 +186,13 @@ class JaxBackend(base.ExecutionBackend):
         t0 = time.perf_counter()
         aot = plan.input_specs is not None and sig is not None
         if aot:
+            # each input carries the sharding the engine placed it in: a
+            # program compiled for unsharded inputs rejects row-block
+            # operands on a multi-device mesh
+            shardings = plan.input_shardings or {}
             abstract = {slot: jax.ShapeDtypeStruct(
-                tuple(int(d) for d in shape), jnp.dtype(dtype))
+                tuple(int(d) for d in shape), jnp.dtype(dtype),
+                sharding=shardings.get(slot))
                 for slot, (shape, dtype) in plan.input_specs.items()}
             program = jax.jit(fused).lower(abstract).compile()
         else:
@@ -197,7 +213,18 @@ class JaxBackend(base.ExecutionBackend):
         return self.get_or_compile(plan)[0]
 
 
-register = JaxBackend.register
+def register(library: str, routine: str, **kw):
+    """Register a jax implementation whose matmuls trace at full float32
+    precision (see the module docstring); the wrapper is what the engine
+    and the fused programs call, the bare function stays importable."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def at_fp32(*args, **kwargs):
+            with jax.default_matmul_precision("highest"):
+                return fn(*args, **kwargs)
+        JaxBackend.register(library, routine, **kw)(at_fp32)
+        return fn
+    return wrap
 
 
 # ---------------------------------------------------------------------------
@@ -238,10 +265,18 @@ def _transpose(A):
     return {"C": A.T}
 
 
+@functools.partial(jax.jit, static_argnames="use_pallas")
+def _gram_matrix(x, use_pallas: bool = False):
+    """G = X^T X on the device: the Pallas Gram kernel when requested.
+    One program, so X^T is never materialized (run op by op, a 7.91 GiB
+    operand's transpose did not fit next to it on one v5e)."""
+    return gram_ops.gram(x, use_pallas=use_pallas)
+
+
 @register("elemental", "gram", fusible=True, accepts=_DENSE,
           bucketable=True, out_shapes=base.shapes_gram)
 def _gram(A, use_pallas: bool = False):
-    return {"G": gram_ops.gram(A, use_pallas=use_pallas)}
+    return {"G": _gram_matrix(A, use_pallas=use_pallas)}
 
 
 @register("elemental", "qr", fusible=True, accepts=_DENSE)
@@ -284,18 +319,20 @@ def _truncated_svd(A, k: int, oversample: int = 32, max_iters: int = 0,
             "lanczos_iters": iters, "matvecs": matvecs}
 
 
-@register("elemental", "gram_svd", fusible=True, accepts=_DENSE)
+@register("elemental", "gram_svd", accepts=_DENSE)
 def _gram_svd(A, k: int, use_pallas: bool = False):
+    """The Gram matrix on the device, its d x d eigenproblem on the host in
+    float64, U = X V back on the device. XLA's TPU eigh is no option at
+    the paper's widths: compiling it for a v5e took 69 s and 4.4 GiB of
+    host memory at d = 1,024 already."""
     x = A
-    g = gram_ops.gram(x, use_pallas=use_pallas)
-    evals, evecs = jnp.linalg.eigh(g)
-    order = jnp.argsort(evals)[::-1][:k]
-    lam = jnp.maximum(evals[order], 0.0)
-    sigma = jnp.sqrt(lam)
-    v = evecs[:, order]
-    u = (x @ v.astype(x.dtype)) / jnp.maximum(sigma.astype(x.dtype), 1e-30)
-    return {"U": u, "S": sigma.astype(jnp.float32),
-            "V": v.astype(jnp.float32)}
+    g = np.asarray(_gram_matrix(x, use_pallas=use_pallas), np.float64)
+    evals, evecs = np.linalg.eigh(g)
+    order = np.argsort(evals)[::-1][:k]
+    sigma = np.sqrt(np.maximum(evals[order], 0.0))
+    v_dev = jnp.asarray(evecs[:, order], x.dtype)
+    u = (x @ v_dev) / jnp.maximum(jnp.asarray(sigma, x.dtype), 1e-30)
+    return {"U": u, "S": jnp.asarray(sigma, jnp.float32), "V": v_dev}
 
 
 @register("elemental", "randomized_svd", accepts=_DENSE)
@@ -324,15 +361,25 @@ def _randomized_svd(A, k: int, oversample: int = 8, power_iters: int = 2,
 # ---------------------------------------------------------------------------
 # skylark
 # ---------------------------------------------------------------------------
+#: the random-feature expansion as one program: run op by op, X W, + b
+#: and the cosine each hold an (n, D) temporary, and the speech CG peaked
+#: at 16.06 GB of a 16 GB v5e
+_rf_expand = jax.jit(rf_ops.rf_map, static_argnames=(
+    "rf_dim", "bandwidth", "seed", "use_pallas"))
+
+
 @register("skylark", "random_features", accepts=_DENSE)
-def _random_features(X, rf_dim: int, bandwidth: float = 1.0, seed: int = 0):
-    return {"Z": rf_ops.rf_map(X, rf_dim, bandwidth=bandwidth, seed=seed)}
+def _random_features(X, rf_dim: int, bandwidth: float = 1.0, seed: int = 0,
+                     use_pallas: bool = False):
+    return {"Z": _rf_expand(X, rf_dim, bandwidth=bandwidth, seed=seed,
+                            use_pallas=use_pallas)}
 
 
 def _cg_step(x, lam_n, state, use_pallas=False):
-    """One CG iteration on the normal equations; with use_pallas the
-    fused normal_matvec kernel streams X once per iteration instead of
-    twice (the CG loop's dominant HBM traffic)."""
+    """One CG iteration on the normal equations; with use_pallas (and a
+    width whose row block fits VMEM, ``nm_ops.uses_kernel``) the fused
+    normal_matvec kernel streams X once per iteration instead of twice
+    (the CG loop's dominant HBM traffic)."""
     w, r, p, rs = state
     ap = nm_ops.normal_matvec(x, p, use_pallas=use_pallas).astype(x.dtype) \
         + lam_n * p
@@ -344,18 +391,56 @@ def _cg_step(x, lam_n, state, use_pallas=False):
     return w, r, p, rs_new
 
 
+@jax.jit
+def _cg_rhs(x, y):
+    """b = X^T Y as one program: run eagerly, X^T is a transpose op that
+    materializes a second (n, D) buffer next to X."""
+    return x.T @ y
+
+
+#: rows per block of the residual evaluation (see :func:`_cg_residual`)
+_RESIDUAL_BLOCK = 1024
+
+
+@jax.jit
+def _cg_residual(x, y, lam_n, b_norm, w):
+    """max over columns of ||X^T Y - (X^T X + lam_n I) w|| / ||b||,
+    evaluated on w itself rather than carried by the CG recurrence, as
+    X^T (Y - X w) - lam_n w summed over blocks of rows. In one float32
+    product over all rows the rounding is of the size of the residual it
+    measures: at 131,072 x 10,000 on a v5e it read 1.34e-5 where float64
+    read 1.88e-5; by blocks of 1,024 rows it read 1.88e-5."""
+    n, d = x.shape
+    blk = min(_RESIDUAL_BLOCK, n)
+    full = n // blk
+
+    def part(xb, yb):
+        return xb.T @ (yb - xb @ w)
+
+    def body(i, acc):
+        return acc + part(jax.lax.dynamic_slice_in_dim(x, i * blk, blk),
+                          jax.lax.dynamic_slice_in_dim(y, i * blk, blk))
+
+    acc = jax.lax.fori_loop(0, full, body, jnp.zeros_like(w))
+    if full * blk < n:
+        acc = acc + part(x[full * blk:], y[full * blk:])
+    return jnp.max(jnp.linalg.norm(acc - lam_n * w, axis=0)
+                   / jnp.maximum(b_norm, 1e-30))
+
+
 @register("skylark", "cg_solve", accepts=_DENSE)
 def _cg_solve(X, Y, lam: float = 1e-5, rf_dim: int = 0,
               bandwidth: float = 1.0, max_iters: int = 200,
               tol: float = 1e-8, seed: int = 0, use_pallas: bool = False):
     x = X
     if rf_dim:
-        x = rf_ops.rf_map(x, rf_dim, bandwidth=bandwidth, seed=seed)
+        x = _rf_expand(x, rf_dim, bandwidth=bandwidth, seed=seed,
+                       use_pallas=use_pallas)
     y = Y
     n, d = x.shape
     lam_n = jnp.asarray(n * lam, x.dtype)
 
-    b = x.T @ y                                  # (d, c) rhs
+    b = _cg_rhs(x, y)                            # (d, c) rhs
     b_norm = jnp.linalg.norm(b, axis=0)
     w = jnp.zeros(b.shape, x.dtype)
     r = b
@@ -376,13 +461,26 @@ def _cg_solve(X, Y, lam: float = 1e-5, rf_dim: int = 0,
         rel = float(jnp.max(jnp.sqrt(state[3])
                             / jnp.maximum(b_norm, 1e-30)))
         history.append(rel)
+    # in float32 the recurrence drifts from the true residual: on a v5e
+    # it fell to 7e-8 while b - A w stalled at 2e-5. The loop stops on
+    # the recurrence; what is reported is the true residual of the W
+    # returned, one more pass over the rows.
+    true_rel = float(_cg_residual(x, y.astype(x.dtype), lam_n, b_norm,
+                                  state[0]))
 
     return {
         "W": state[0],
         "iterations": iters,
-        "relative_residual": rel,
+        "relative_residual": true_rel,
         "residual_history": [float(h) for h in history],
         "expanded_dim": int(d),
+        # which implementation ran each stage: a requested kernel that
+        # gave way to the jnp reference by shape shows here
+        "kernels": {
+            "rf_map": ("pallas" if use_pallas else "jnp") if rf_dim
+            else "none",
+            "normal_matvec": "pallas" if nm_ops.uses_kernel(d, use_pallas)
+            else "jnp"},
     }
 
 

@@ -164,7 +164,7 @@ def _truncated_svd(A, k: int, oversample: int = 32, max_iters: int = 0,
             "lanczos_iters": iters, "matvecs": matvecs}
 
 
-@register("elemental", "gram_svd", fusible=True, accepts=_DENSE)
+@register("elemental", "gram_svd", accepts=_DENSE)
 def _gram_svd(A, k: int, use_pallas: bool = False):
     g = np.asarray(A.T @ A, np.float64)
     evals, evecs = np.linalg.eigh(g)
@@ -208,7 +208,9 @@ def _np_rf_map(x: np.ndarray, rf_dim: int, bandwidth: float,
 
 
 @register("skylark", "random_features", accepts=_DENSE)
-def _random_features(X, rf_dim: int, bandwidth: float = 1.0, seed: int = 0):
+def _random_features(X, rf_dim: int, bandwidth: float = 1.0, seed: int = 0,
+                     use_pallas: bool = False):
+    # use_pallas is a jax-backend knob; the reference result is the same
     return {"Z": _np_rf_map(X, rf_dim, bandwidth, seed)}
 
 
@@ -245,13 +247,18 @@ def _cg_solve(X, Y, lam: float = 1e-5, rf_dim: int = 0,
         iters += 1
         rel = float(np.max(np.sqrt(rs) / np.maximum(b_norm, 1e-30)))
         history.append(rel)
+    # reported as on the jax backend: the true residual of the W returned
+    true_rel = float(np.max(np.linalg.norm(
+        b - (x.T @ (x @ w) + lam_n * w), axis=0) / np.maximum(b_norm, 1e-30)))
 
     return {
         "W": w,
         "iterations": iters,
-        "relative_residual": rel,
+        "relative_residual": true_rel,
         "residual_history": [float(h) for h in history],
         "expanded_dim": int(d),
+        "kernels": {"rf_map": "numpy" if rf_dim else "none",
+                    "normal_matvec": "numpy"},
     }
 
 

@@ -30,8 +30,9 @@ the Alchemist engine):
   path (``AlchemistEngine.warmup`` / ``warmup_on_load``): the first
   tenant to submit a bucketed shape never sees a trace.
 * **Persistence** — :func:`enable_persistent_cache` turns on JAX's
-  persistent compilation cache (XLA executables keyed by HLO, on disk),
-  and :class:`ExecutableIndex` is the engine-level index over it: every
+  persistent compilation cache (XLA executables keyed by HLO, on disk)
+  in the one directory :func:`cache_dir` resolves, and
+  :class:`ExecutableIndex` is the engine-level index over it: every
   compiled plan (structure + input specs) is recorded, so a restarted
   engine can re-AOT exactly the programs it served before — the re-lower
   hits JAX's disk cache instead of recompiling, and tenant traffic after
@@ -158,29 +159,58 @@ def propagate_shapes(plan: backend_base.ExecutionPlan,
 
 
 # ---------------------------------------------------------------------------
-# persistent compilation cache (the JAX disk cache, engine-configured)
+# persistent compilation cache (the JAX disk cache, one directory)
 # ---------------------------------------------------------------------------
-def enable_persistent_cache(cache_dir: str) -> bool:
-    """Point JAX's persistent compilation cache at ``cache_dir`` so XLA
-    executables survive process restarts. The thresholds are zeroed:
-    this repo's programs are small, fast compiles — exactly what the
-    default ``min_compile_time_secs=1.0`` would refuse to persist.
+#: the checkout's own cache directory (gitignored), used when
+#: ``JAX_COMPILATION_CACHE_DIR`` is unset. A fixed path, never one built
+#: from a temporary name, a pid or the time: the path is part of the
+#: cache's key, so a directory that moves never hits.
+DEFAULT_CACHE_DIR = os.path.abspath(os.path.join(
+    os.path.dirname(__file__), "..", "..", "..", ".jax_cache"))
 
-    Process-global by necessity (it is a JAX config); the engine calls
-    it at construction when given ``compile_cache_dir``. Returns False
-    (instead of raising) when this JAX build lacks the config knobs —
-    the engine-level index still works, only cross-process executable
-    reuse degrades to plain recompiles."""
+
+def cache_dir() -> str:
+    """The one place compiled programs are kept:
+    ``JAX_COMPILATION_CACHE_DIR`` when it is set, otherwise
+    :data:`DEFAULT_CACHE_DIR`. Nothing else (no constructor argument,
+    CLI flag or wire option) can point the cache elsewhere."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or DEFAULT_CACHE_DIR
+
+
+def enable_persistent_cache() -> str:
+    """Turn on JAX's persistent compilation cache at :func:`cache_dir`
+    and return the directory. Entry points call it once at start
+    (``chip_smoke.py``, ``python -m repro.core.server``, the
+    benchmarks); engines built afterwards keep their
+    :class:`ExecutableIndex` in the same directory
+    (:func:`active_cache_dir`).
+
+    The thresholds are zeroed: this repo's programs are small, fast
+    compiles — exactly what the default ``min_compile_time_secs=1.0``
+    would refuse to persist. Process-global by necessity (it is a JAX
+    config), which is why only entry points call it."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    path = cache_dir()
+    os.makedirs(path, exist_ok=True)
+    jax.config.update("jax_enable_compilation_cache", True)
+    jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # JAX opens its cache once; re-open it at the configured path
+    compilation_cache.reset_cache()
+    return path
+
+
+def active_cache_dir() -> Optional[str]:
+    """Where JAX's persistent cache writes in this process, or None when
+    it is off — the directory an engine keeps its executable index in."""
     import jax
 
-    os.makedirs(cache_dir, exist_ok=True)
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        return True
-    except Exception:
-        return False
+    if not jax.config.jax_enable_compilation_cache:
+        return None
+    return jax.config.jax_compilation_cache_dir or None
 
 
 # ---------------------------------------------------------------------------
@@ -235,6 +265,8 @@ def plan_record(backend: str, plan: backend_base.ExecutionPlan,
                   for s in plan.steps],
         "input_specs": {slot: [list(shape), dtype]
                         for slot, (shape, dtype) in plan.input_specs.items()},
+        "input_layouts": dict(plan.input_layouts)
+        if plan.input_layouts is not None else None,
         "compile_s": round(float(compile_s), 6),
     }
     try:
@@ -261,7 +293,9 @@ def plan_from_record(rec: dict, backend: backend_base.ExecutionBackend
                 impl=impl))
         specs = {slot: (tuple(int(d) for d in shape), str(dtype))
                  for slot, (shape, dtype) in rec["input_specs"].items()}
-        return backend_base.ExecutionPlan(steps=steps, input_specs=specs)
+        return backend_base.ExecutionPlan(
+            steps=steps, input_specs=specs,
+            input_layouts=rec.get("input_layouts"))
     except Exception:
         return None
 

@@ -58,11 +58,6 @@ OPTIONS: tuple[ConfigOption, ...] = (
             "path (True = default bucket grid; a list of ints = that "
             "grid)"),
     ConfigOption(
-        name="cache_dir", kind="str", scope=SCOPE_ENGINE,
-        cli="--compile-cache-dir",
-        doc="persistent compile cache directory (engine-wide by nature "
-            "— the JAX disk cache is process-global)"),
-    ConfigOption(
         name="weight", kind="number > 0", scope=SCOPE_SESSION,
         requires_qos=True,
         doc="fair-share weight of this tenant on the worker pool "

@@ -187,7 +187,7 @@ class AlchemistContext:
     def configure(self, backend: Optional[str] = None,
                   fusion: Optional[bool] = None,
                   bucketing: Optional[bool] = None,
-                  warmup=None, cache_dir: Optional[str] = None,
+                  warmup=None,
                   weight: Optional[float] = None,
                   quotas: Optional[dict] = None) -> dict:
         """Select this session's execution environment over the
@@ -198,9 +198,7 @@ class AlchemistContext:
         command dispatches as its own task); ``bucketing`` opts this
         session in/out of operand shape bucketing; ``warmup=True`` (or a
         list of bucket sizes) AOT-compiles the bucketable catalog and
-        indexed hot signatures right now, off the request path;
-        ``cache_dir`` points the engine at a persistent compile cache
-        (engine-wide — XLA executables survive restarts). On a
+        indexed hot signatures right now, off the request path. On a
         QoS-enabled engine (``AlchemistEngine(qos=True)``), ``weight``
         sets this session's fair-share weight (default 1.0; a weight-2
         tenant earns twice the dispatch share) and ``quotas`` overrides
@@ -220,8 +218,6 @@ class AlchemistContext:
         if warmup is not None:
             options["warmup"] = list(warmup) \
                 if isinstance(warmup, (list, tuple)) else warmup
-        if cache_dir is not None:
-            options["cache_dir"] = cache_dir
         if weight is not None:
             options["weight"] = weight
         if quotas is not None:

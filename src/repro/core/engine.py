@@ -48,10 +48,12 @@ The engine owns
   one dispatch for N commands, chain-internal values never materialized
   between steps (``task_log.stats()`` reports the fused-ops ratio).
 
-On this CPU container the worker mesh is however many devices exist (1);
-the same code lowers onto a real multi-chip engine mesh unchanged — the
-engine is given its mesh at construction, exactly like Alchemist being
-launched on "a user-specified number of nodes" (§3.1.1).
+The worker mesh is a 1-D ``workers`` axis over the devices the engine is
+given at construction (one TPU chip, four, or the CPU in tests), exactly
+like Alchemist being launched on "a user-specified number of nodes"
+(§3.1.1). Operands land row-blocked over it when their rows divide the
+mesh and replicated otherwise; ``placement_stats`` counts the
+replicated ones, so a copy on every chip is never silent.
 """
 from __future__ import annotations
 
@@ -230,10 +232,12 @@ class AlchemistEngine:
     ``fuse_chains=False`` disables chain claiming engine-wide (every
     command dispatches as its own task — the pre-ABI behaviour).
 
-    Compile-latency subsystem (``core/compilecache.py``):
-    ``compile_cache_dir`` turns on the JAX persistent compilation cache
-    plus the engine-level :class:`~repro.core.compilecache.ExecutableIndex`
-    (compiled programs survive restarts); ``bucketing``/``bucket_grid``
+    Compile-latency subsystem (``core/compilecache.py``): when the
+    process turned on JAX's persistent compilation cache
+    (``compilecache.enable_persistent_cache``, called once by entry
+    points), the engine keeps its
+    :class:`~repro.core.compilecache.ExecutableIndex` in the same
+    directory (compiled programs survive restarts); ``bucketing``/``bucket_grid``
     set the engine-default shape-bucket policy (sessions override via
     ``configure``); ``warmup_on_load`` AOT-compiles the bucketable
     catalog (and every indexed hot signature) in the background whenever
@@ -257,7 +261,6 @@ class AlchemistEngine:
                  cache_entries: int = 256,
                  backend: str = backend_registry.DEFAULT_BACKEND,
                  fuse_chains: bool = True,
-                 compile_cache_dir: Optional[str] = None,
                  bucketing: bool = True,
                  bucket_grid=None,
                  warmup_on_load: bool = False,
@@ -314,15 +317,21 @@ class AlchemistEngine:
             else compilecache.DEFAULT_WARMUP_GRID
         self.warmup_on_load = bool(warmup_on_load)
         self.compile_log = CompileLog()
-        self.compile_cache_dir: Optional[str] = None
-        self._exec_index: Optional[compilecache.ExecutableIndex] = None
+        # the executable index lives beside JAX's persistent cache
+        # (None when the process never turned the cache on)
+        self.compile_cache_dir: Optional[str] = \
+            compilecache.active_cache_dir()
+        self._exec_index: Optional[compilecache.ExecutableIndex] = \
+            compilecache.ExecutableIndex(self.compile_cache_dir) \
+            if self.compile_cache_dir else None
         self._warmup_threads: list[threading.Thread] = []
         if program_cache_size is not None:
             for be in self.backends.values():
                 if hasattr(be, "max_programs"):
                     be.max_programs = int(program_cache_size)
-        if compile_cache_dir:
-            self._set_cache_dir(compile_cache_dir)
+        # stores held as a full copy on every device of a multi-device
+        # mesh (rows not divisible by it): counted, never silent
+        self._replicated = {"stores": 0, "bytes": 0}
         # Session 0 is the always-present system namespace: in-process
         # callers (engine-side services, the trainer) that bypass the
         # protocol operate in it.
@@ -582,8 +591,7 @@ class AlchemistEngine:
         """Protocol endpoint for session configuration: select the
         execution backend this session's commands run in (validated
         against the registry), toggle chain fusion or shape
-        ``bucketing``, point the engine at a persistent compile
-        ``cache_dir``, and/or trigger an AOT ``warmup`` pass (True =
+        ``bucketing``, and/or trigger an AOT ``warmup`` pass (True =
         default bucket grid; a list of ints = that grid) — the warmup
         runs synchronously here, at configure time, which is exactly the
         off-request-path moment the compile latency belongs in. Replies
@@ -634,10 +642,6 @@ class AlchemistEngine:
                     raise TypeError(
                         "configure option 'warmup' must be a bool or a "
                         "list of bucket sizes")
-            if "cache_dir" in cfg.options and \
-                    not isinstance(cfg.options["cache_dir"], str):
-                raise TypeError(
-                    "configure option 'cache_dir' must be a str path")
             quotas = None
             if any(o in cfg.options for o in configopts.QOS_OPTIONS):
                 if not self.qos_enabled:
@@ -661,10 +665,6 @@ class AlchemistEngine:
                     sess.fusion = cfg.options["fusion"]
                 if "bucketing" in cfg.options:
                     sess.bucketing = cfg.options["bucketing"]
-                if "cache_dir" in cfg.options:
-                    # engine-wide by nature (the JAX disk cache is a
-                    # process-global config) — documented, not hidden
-                    self._set_cache_dir(cfg.options["cache_dir"])
                 if "weight" in cfg.options:
                     sess.weight = float(cfg.options["weight"])
                 effective = {
@@ -674,7 +674,6 @@ class AlchemistEngine:
                     "bucketing": sess.bucketing
                     if sess.bucketing is not None
                     else self.bucket_policy.enabled,
-                    "cache_dir": self.compile_cache_dir or "",
                 }
             if "weight" in cfg.options:
                 # rank order: scheduler.cv (20) nests fine above the
@@ -706,15 +705,6 @@ class AlchemistEngine:
         return sess.backend
 
     # ---- compile-latency subsystem (shape buckets + AOT + persistence) ----
-    def _set_cache_dir(self, cache_dir: str) -> None:
-        """Point the engine at a persistent compile cache dir: JAX's disk
-        cache (XLA executables survive restarts) plus the engine-level
-        executable index over it. Engine-wide: the JAX knob is a
-        process-global config."""
-        self.compile_cache_dir = cache_dir
-        compilecache.enable_persistent_cache(cache_dir)
-        self._exec_index = compilecache.ExecutableIndex(cache_dir)
-
     def _session_policy(self, sess: Optional[Session]
                         ) -> compilecache.BucketPolicy:
         """The bucket policy effective for one session (its override, or
@@ -763,6 +753,16 @@ class AlchemistEngine:
                 crops = None    # rule rejected: run exact, real error
         plan.input_specs = {s: (tuple(a.shape), str(a.dtype))
                             for s, a in run_inputs.items()}
+        if hasattr(backend, "pad_to"):
+            # every operand enters the program in its operand's layout,
+            # placed by this engine's mesh: the compiled program's input
+            # shardings then match the arrays it is called with (a
+            # padded copy would otherwise carry whatever sharding the
+            # pad produced)
+            layouts = {s: self.layout_of(inputs[s]) for s in run_inputs}
+            self._stamp_placement(plan, layouts)
+            run_inputs = {s: self._place(a, plan.input_shardings[s])
+                          for s, a in run_inputs.items()}
         program, info = backend.get_or_compile(plan)
         self._account_compile(backend, plan, info,
                               session=sess.id if sess else SYSTEM_SESSION,
@@ -839,6 +839,9 @@ class AlchemistEngine:
         grid_t = tuple(int(g) for g in (grid or self.warmup_grid))
 
         def compile_plan(plan, bucketed):
+            self._stamp_placement(plan, plan.input_layouts or {
+                s: self.layout_of_sharding(self.dist_sharding(shape))
+                for s, (shape, _) in plan.input_specs.items()})
             program, info = be.get_or_compile(plan)
             stats["cached" if info["cached"] else "compiled"] += 1
             self._account_compile(be, plan, info, session=session,
@@ -870,6 +873,8 @@ class AlchemistEngine:
                     slot = f"i{len(slots)}"
                     slots[slot] = combo[k]
                     args[k] = backend_base.Input(slot)
+                # operands in the layout uploads land in (dist_sharding),
+                # the programs bucketed tenant requests will ask for
                 plan = backend_base.ExecutionPlan(
                     steps=[backend_base.PlanStep(
                         library=lib, routine=rn, args=args, impl=impl)],
@@ -1093,6 +1098,9 @@ class AlchemistEngine:
                 sharding=getattr(array, "sharding", None),
                 layout=lay)
             self._by_fingerprint.setdefault(fp, store_id)
+            if lay == REPLICATED and self.num_workers > 1:
+                self._replicated["stores"] += 1
+                self._replicated["bytes"] += nbytes
             if self._stm.enabled:
                 self._stm.mint("store", (self._stm_dom, store_id),
                                site="put")
@@ -1513,6 +1521,36 @@ class AlchemistEngine:
                 self._release_entry_outputs(old)
 
     # ---- 2D engine layout (Elemental DistMatrix analogue) ----
+    def _stamp_placement(self, plan: backend_base.ExecutionPlan,
+                         layouts: dict[str, str]) -> None:
+        """Record each plan input's layout (part of the program's
+        signature) and the sharding realizing it on this mesh (what the
+        AOT compile lowers against). The tag is read back off the
+        realized sharding, so a layout the shape cannot satisfy is keyed
+        as what it became."""
+        shardings = {s: self.sharding_for(plan.input_specs[s][0], lay)
+                     for s, lay in layouts.items()}
+        plan.input_shardings = shardings
+        plan.input_layouts = {s: self.layout_of_sharding(sh)
+                              for s, sh in shardings.items()}
+
+    @staticmethod
+    def _place(arr, sharding):
+        """``arr`` in ``sharding``: a no-op when it already is (one
+        device: always), one ``device_put`` otherwise."""
+        current = getattr(arr, "sharding", None)
+        if current is not None and \
+                current.is_equivalent_to(sharding, arr.ndim):
+            return arr
+        return jax.device_put(arr, sharding)
+
+    def placement_stats(self) -> dict:
+        """How many stores the engine has put as a full copy on every
+        device of a multi-device mesh (rows not divisible by it), and
+        their bytes. Always zero on one device."""
+        with self._state_lock:
+            return dict(self._replicated, workers=self.num_workers)
+
     def dist_sharding(self, shape) -> NamedSharding:
         """Engine-native sharding for ``shape``: rows over the worker axis
         when they divide evenly (the DistMatrix row-block layout),
@@ -1549,7 +1587,12 @@ class AlchemistEngine:
         Arrays with no named sharding (host arrays, single-device
         results never resharded) are a full copy wherever they live:
         ``replicated``."""
-        sharding = getattr(array, "sharding", None)
+        return self.layout_of_sharding(getattr(array, "sharding", None))
+
+    @staticmethod
+    def layout_of_sharding(sharding) -> str:
+        """The layout tag a device sharding realizes (see
+        :meth:`layout_of`)."""
         spec = getattr(sharding, "spec", None)
         if spec is None:
             return REPLICATED

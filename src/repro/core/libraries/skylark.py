@@ -19,9 +19,10 @@ from repro.core.libraries.spec import routine, spec_only
 
 @routine(outputs=("Z",))
 def random_features(engine, X, rf_dim: int, bandwidth: float = 1.0,
-                    seed: int = 0):
+                    seed: int = 0, use_pallas: bool = False):
     """Z = sqrt(2/D) cos(X W / sigma + b) — expansion happens on the engine
-    (paper: 'the feature matrix is instead expanded within Alchemist')."""
+    (paper: 'the feature matrix is instead expanded within Alchemist').
+    The same expansion ``cg_solve`` makes with the same arguments."""
     raise spec_only("skylark", "random_features")
 
 

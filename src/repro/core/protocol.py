@@ -117,9 +117,7 @@ class Configure:
     (bool; opt this session in/out of operand shape bucketing),
     ``warmup`` (True, or a list of bucket sizes: AOT-compile the
     bucketable catalog + indexed hot signatures now, off the request
-    path), ``cache_dir`` (str; engine-wide persistent compile cache
-    directory — see ``core/compilecache.py``), and — on QoS-enabled
-    engines only — ``weight`` (positive number; this tenant's
+    path), and — on QoS-enabled engines only — ``weight`` (positive number; this tenant's
     fair-share dispatch weight) and ``quotas`` (dict; per-session
     admission quota overrides). The full option table lives in
     ``core/configopts.py`` (the CFG001 rule keeps every surface in

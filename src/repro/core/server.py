@@ -42,7 +42,7 @@ import msgpack
 import numpy as np
 
 from repro.analysis import locktrace, statemachine
-from repro.core import protocol, transfer, wire
+from repro.core import compilecache, protocol, transfer, wire
 from repro.core.costmodel import WireLog
 from repro.core.engine import SYSTEM_SESSION, AlchemistEngine, \
     make_engine_mesh
@@ -601,10 +601,6 @@ def main(argv: Optional[list[str]] = None) -> int:
     ap.add_argument("--port", type=int, default=DEFAULT_PORT)
     ap.add_argument("--workers", type=int, default=None,
                     help="engine mesh size (default: all local devices)")
-    ap.add_argument("--compile-cache-dir", default=None,
-                    help="persist compiled XLA executables here (plus the "
-                    "engine's executable index) so restarts skip "
-                    "recompiling — see core/compilecache.py")
     ap.add_argument("--warmup", action="store_true",
                     help="AOT-compile the bucketable catalog and every "
                     "indexed hot signature before accepting traffic "
@@ -616,9 +612,12 @@ def main(argv: Optional[list[str]] = None) -> int:
                     help="bound on live compiled programs per backend "
                     "(LRU; default 128)")
     args = ap.parse_args(argv)
+    # compiled programs (and the engine's executable index) persist in
+    # the one cache directory, so restarts skip recompiling
+    cache = compilecache.enable_persistent_cache()
+    print(f"compile cache: {cache}", flush=True)
     engine = AlchemistEngine(
         make_engine_mesh(args.workers),
-        compile_cache_dir=args.compile_cache_dir,
         bucketing=not args.no_bucketing,
         warmup_on_load=args.warmup,
         program_cache_size=args.program_cache_size)

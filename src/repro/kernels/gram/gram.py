@@ -36,7 +36,7 @@ def _gram_kernel(a_i_ref, a_j_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "interpret"))
 def gram_pallas(a: jnp.ndarray, *, bm: int = 512, bn: int = 256,
-                interpret: bool = True) -> jnp.ndarray:
+                interpret: bool) -> jnp.ndarray:
     """G = A^T A. Requires n % bm == 0 and d % bn == 0 (ops.py pads)."""
     n, d = a.shape
     assert n % bm == 0 and d % bn == 0, (a.shape, bm, bn)

@@ -43,7 +43,7 @@ def _lru_kernel(a_ref, b_ref, h0_ref, o_ref, h_ref, *, bt: int):
 @functools.partial(jax.jit, static_argnames=("bt", "bw", "interpret"))
 def lru_scan_pallas(a: jnp.ndarray, b: jnp.ndarray, h0: jnp.ndarray, *,
                     bt: int = 128, bw: int = 512,
-                    interpret: bool = True) -> jnp.ndarray:
+                    interpret: bool) -> jnp.ndarray:
     """a, b: (B, S, W); h0: (B, W). Requires S % bt == 0 and W % bw == 0.
     Returns all states (B, S, W) fp32."""
     bb, s, w = a.shape

@@ -38,7 +38,7 @@ def _nm_kernel(x_ref, w_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("bm", "interpret"))
 def normal_matvec_pallas(x: jnp.ndarray, w: jnp.ndarray, *, bm: int = 128,
-                         interpret: bool = True) -> jnp.ndarray:
+                         interpret: bool) -> jnp.ndarray:
     """x: (n, d), w: (d, c); n % bm == 0 (ops pads). Returns (d, c) fp32."""
     n, d = x.shape
     c = w.shape[1]

@@ -3,39 +3,33 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro import kernels
 from repro.kernels.rf_map.ref import rf_map_ref, rf_weights
 from repro.kernels.rf_map.rf_map import rf_map_pallas
 
 
 def rf_map(x: jnp.ndarray, rf_dim: int, *, bandwidth: float = 1.0,
-           seed: int = 0, use_pallas: bool = False,
-           interpret: bool = True) -> jnp.ndarray:
+           seed: int = 0, use_pallas: bool = False) -> jnp.ndarray:
     """Z = sqrt(2/D) cos(X W + b) with internally generated (W, b)."""
     w, b = rf_weights(x.shape[1], rf_dim, bandwidth, seed)
-    return rf_map_apply(x, w, b, use_pallas=use_pallas, interpret=interpret)
+    return rf_map_apply(x, w, b, use_pallas=use_pallas)
 
 
 def rf_map_apply(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray, *,
-                 use_pallas: bool = False, interpret: bool = True
-                 ) -> jnp.ndarray:
+                 use_pallas: bool = False) -> jnp.ndarray:
     if not use_pallas:
         return rf_map_ref(x, w, b)
-    n, d = x.shape
-    dd = w.shape[1]
-    bm, bn, bk = 256, 256, 128
+    bk = 128
 
-    def pad(a, m, axis):
-        rem = a.shape[axis] % m
+    def pad(a, axis):
+        rem = a.shape[axis] % bk
         if rem == 0:
             return a
         padspec = [(0, 0)] * a.ndim
-        padspec[axis] = (0, m - rem)
+        padspec[axis] = (0, bk - rem)
         return jnp.pad(a, padspec)
 
-    xp = pad(pad(x, bm, 0), bk, 1)
-    wp = pad(pad(w, bk, 0), bn, 1)
-    bp = pad(b, bn, 0)
-    z = rf_map_pallas(xp, wp, bp, bm=bm, bn=bn, bk=bk, interpret=interpret)
-    # padded output scale uses padded D; rescale to the true dimension
-    z = z * jnp.sqrt(jnp.asarray(wp.shape[1] / dd, jnp.float32))
-    return z[:n, :dd]
+    # only the contraction dim needs zeros; the kernel writes (n, D)
+    # exactly, so nothing is sliced (or rescaled) afterwards
+    return rf_map_pallas(pad(x, 1), pad(w, 0), b, bk=bk,
+                         interpret=kernels.interpret_mode())

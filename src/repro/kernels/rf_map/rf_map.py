@@ -41,17 +41,21 @@ def _rf_kernel(x_ref, w_ref, b_ref, o_ref, *, nk: int, scale: float):
                    static_argnames=("bm", "bn", "bk", "interpret"))
 def rf_map_pallas(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray, *,
                   bm: int = 256, bn: int = 256, bk: int = 128,
-                  interpret: bool = True) -> jnp.ndarray:
-    """x: (n, d), w: (d, D), b: (D,). Requires divisible dims (ops pads)."""
+                  interpret: bool) -> jnp.ndarray:
+    """x: (n, d), w: (d, D), b: (D,); d % bk == 0 (ops zero-pads the
+    contraction). Returns exactly (n, D): edge blocks along n and D are
+    partial (reads past the edge only feed output cells that are never
+    written), so the largest tensor of the workload is written once, at
+    its own size, with no pad-and-slice copy."""
     n, d = x.shape
     d2, dd = w.shape
-    assert d == d2 and n % bm == 0 and dd % bn == 0 and d % bk == 0
+    assert d == d2 and d % bk == 0, (x.shape, w.shape, bk)
     nk = d // bk
     scale = float((2.0 / dd) ** 0.5)
     kernel = functools.partial(_rf_kernel, nk=nk, scale=scale)
     return pl.pallas_call(
         kernel,
-        grid=(n // bm, dd // bn, nk),
+        grid=(pl.cdiv(n, bm), pl.cdiv(dd, bn), nk),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),

@@ -68,7 +68,7 @@ def _swa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
                    static_argnames=("window", "bq", "bk", "interpret"))
 def swa_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                window: int, bq: int = 128, bk: int = 128,
-               interpret: bool = True) -> jnp.ndarray:
+               interpret: bool) -> jnp.ndarray:
     """q,k,v: (BH, S, D) flattened over batch*heads. S % bq == 0 == S % bk."""
     bh, s, d = q.shape
     assert s % bq == 0 and s % bk == 0 and bq % bk == 0, (s, bq, bk)

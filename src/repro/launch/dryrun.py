@@ -145,9 +145,6 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
     t_compile = time.perf_counter() - t0
 
     cost = compiled.cost_analysis() or {}
-    # jax 0.4.x returns [per-computation dict]; 0.6+ returns the dict itself
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
     try:
         mem = compiled.memory_analysis()
         mem_info = {

@@ -35,15 +35,6 @@ from repro.common.sharding import active_rules, with_logical_constraint
 from repro.nn.core import ParamSpec, fan_in_init
 from repro.nn.mlp import mlp_apply, mlp_spec
 
-# jax >= 0.6 exposes shard_map at the top level (replication check renamed
-# check_vma); on the 0.4.x line it lives in jax.experimental as check_rep.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _SHARD_MAP_CHECK = {"check_vma": False}
-else:  # pragma: no cover - exercised on jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_CHECK = {"check_rep": False}
-
 
 def moe_spec(cfg: ModelConfig):
     m: MoEConfig = cfg.moe
@@ -184,13 +175,13 @@ def moe_apply(
             return y.reshape(bb, ss, dd), aux
 
         bspec = P(batch_axes if len(batch_axes) > 1 else batch_axes[0], None, None)
-        y, aux = _shard_map(
+        y, aux = jax.shard_map(
             local_fn,
             mesh=mesh,
             in_specs=(bspec, P(None, None), P("model", None, None),
                       P("model", None, None), P("model", None, None)),
             out_specs=(bspec, P()),
-            **_SHARD_MAP_CHECK,
+            check_vma=False,
         )(x, params["router"]["w"], gate_w, up_w, down_w)
     else:
         x_flat = x.reshape(b * s, d)

@@ -186,6 +186,27 @@ def test_conformance_cg_solve(rig):
     np.testing.assert_allclose(out_j["W"], want, atol=1e-3)
 
 
+@pytest.mark.parametrize("n", [2048, 2500])
+def test_cg_true_residual_by_row_blocks(n):
+    """The residual the jax cg_solve reports is that of the normal
+    equations on the W given, summed over whole row blocks and a ragged
+    tail alike."""
+    from repro.core.backends import jax_backend
+    rng = np.random.default_rng(n)
+    x = rng.standard_normal((n, 16)).astype(np.float32)
+    y = rng.standard_normal((n, 3)).astype(np.float32)
+    w = (1e-3 * rng.standard_normal((16, 3))).astype(np.float32)
+    lam_n = 0.5
+    x64, y64, w64 = (a.astype(np.float64) for a in (x, y, w))
+    b64 = x64.T @ y64
+    b_norm = np.linalg.norm(b64, axis=0)
+    r64 = b64 - x64.T @ (x64 @ w64) - lam_n * w64
+    want = np.max(np.linalg.norm(r64, axis=0) / b_norm)
+    got = float(jax_backend._cg_residual(x, y, np.float32(lam_n),
+                                         b_norm.astype(np.float32), w))
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+
+
 def test_conformance_random_matrix_distribution(rig):
     """Seeded creation: cross-backend bitwise equality is not promised
     (numpy cannot replay jax's counter PRNG) — the contract is the spec

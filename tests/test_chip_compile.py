@@ -1,0 +1,142 @@
+"""Compile rehearsals for a TPU v5e that is described, not attached: the
+served kernels at the paper's widths with ``interpret=False``, and one
+fused engine program over a four-device mesh with row-block operands.
+
+The topology is described inside a module fixture (never at import), so
+every test worker collects the same tests and only the worker given this
+file loads the TPU compiler. JAX's persistent cache is off around these
+compiles: an entry written for a described chip cannot be read back
+without one.
+"""
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
+    SingleDeviceSharding
+
+import repro.kernels
+from repro.core.backends import base as backend_base
+from repro.core.backends.jax_backend import JaxBackend
+from repro.kernels.gram import ops as gram_ops
+from repro.kernels.normal_matvec import ops as nm_ops
+from repro.kernels.rf_map import ops as rf_ops
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot"
+        jax.config.update("jax_enable_compilation_cache", was)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def compiled_kernels(monkeypatch):
+    """The wrappers decide interpret mode from the default backend, which
+    is the CPU here; steer them to the compiled kernel, as on the chip."""
+    monkeypatch.setattr(repro.kernels, "interpret_mode", lambda: False)
+
+
+def _spec(shape, sharding):
+    return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
+
+
+def _compile(fn, *args):
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(fn).lower(*args).compile()
+
+
+@pytest.mark.parametrize("kernel,shapes,fn", [
+    # ocean width: 8,096 columns, padded to 8,192 by the wrapper
+    ("gram", [(65_536, 8_096)],
+     lambda a: gram_ops.gram(a, use_pallas=True)),
+    # speech: d=440 raw features, 147 classes
+    ("normal_matvec", [(131_072, 440), (440, 147)],
+     lambda x, w: nm_ops.normal_matvec(x, w, use_pallas=True)),
+    # speech: 440 -> 10,000 random features
+    ("rf_map", [(131_072, 440), (440, 10_000), (10_000,)],
+     lambda x, w, b: rf_ops.rf_map_apply(x, w, b, use_pallas=True)),
+])
+def test_kernel_compiles_for_v5e_at_paper_width(kernel, shapes, fn,
+                                                 one_chip,
+                                                 compiled_kernels):
+    compiled = _compile(fn, *[_spec(s, one_chip) for s in shapes])
+    assert "tpu_custom_call" in compiled.as_text(), kernel
+    mem = compiled.memory_analysis()
+    # one program's buffers fit the chip's 16 GB
+    total = mem.argument_size_in_bytes + mem.output_size_in_bytes + \
+        mem.temp_size_in_bytes
+    assert total < 15 * 2 ** 30, (kernel, total)
+
+
+def test_rf_map_kernel_writes_its_output_once(one_chip, compiled_kernels):
+    """The expansion is the workload's largest tensor: the kernel writes
+    (n, D) exactly, with no padded copy to slice and rescale afterwards.
+    At D=10,000 (not a multiple of 128) the compiler still keeps one
+    relayout temporary of about the output's size: two copies of Z, not
+    the three a pad-and-slice wrapper held (about 15 GiB, next to no
+    headroom on a 16 GB chip)."""
+    n, d, dd = 131_072, 440, 10_000
+    compiled = _compile(
+        lambda x, w, b: rf_ops.rf_map_apply(x, w, b, use_pallas=True),
+        _spec((n, d), one_chip), _spec((d, dd), one_chip),
+        _spec((dd,), one_chip))
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes == n * dd * 4
+    assert mem.temp_size_in_bytes < 1.25 * n * dd * 4
+
+
+def test_fused_engine_program_compiles_on_four_chip_mesh(topo):
+    """A bucketed gram -> multiply -> add chain, compiled by the backend's
+    AOT path from row-block operands on a 2x2 v5e mesh: the program takes
+    its inputs sharded, and the row-block Gram reduces across chips."""
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("workers",))
+    rowblock = NamedSharding(mesh, P("workers", None))
+    be = JaxBackend()
+    gram = be.routine_impl("elemental", "gram")
+    mul = be.routine_impl("elemental", "multiply")
+    add = be.routine_impl("elemental", "add")
+    plan = backend_base.ExecutionPlan(
+        steps=[
+            backend_base.PlanStep(
+                library="elemental", routine="gram",
+                args={"A": backend_base.Input("i0")}, impl=gram),
+            backend_base.PlanStep(
+                library="elemental", routine="multiply",
+                args={"A": backend_base.StepRef(0, "G"),
+                      "B": backend_base.StepRef(0, "G")}, impl=mul),
+            backend_base.PlanStep(
+                library="elemental", routine="add",
+                args={"A": backend_base.StepRef(1, "C"),
+                      "B": backend_base.StepRef(0, "G")}, impl=add),
+        ],
+        input_specs={"i0": ((262_144, 8_192), "float32")},
+        input_layouts={"i0": "rowblock"},
+        input_shardings={"i0": rowblock})
+    program, info = be.get_or_compile(plan)
+    assert info["aot"] and not info["cached"]
+    text = program.as_text()
+    assert "all-reduce" in text or "reduce-scatter" in text
+    [in_sharding] = jax.tree_util.tree_leaves(program.input_shardings)
+    assert in_sharding.is_equivalent_to(rowblock, 2)

@@ -3,8 +3,10 @@ units, pad/crop conformance of every bucketable cataloged routine
 against the reference backend at odd (non-bucket) shapes, shape-aware
 plan signatures, the program-cache LRU bound, AOT warmup, the
 persistent executable index + warm-restart zero-recompile round trip,
-fused chains with bucketing on/off, CompileLog accounting, and the
-``configure`` wire surface (bucketing/warmup/cache_dir options)."""
+fused chains with bucketing on/off, CompileLog accounting, the one
+cache-directory rule, and the ``configure`` wire surface
+(bucketing/warmup options; no option can move the cache)."""
+import os
 import threading
 
 import numpy as np
@@ -27,6 +29,27 @@ ODD_A = RNG.randn(37, 53).astype(np.float32)
 ODD_B = RNG.randn(53, 29).astype(np.float32)
 ODD_C = RNG.randn(37, 53).astype(np.float32)
 ODD_SQ = (RNG.randn(19, 19) / 4.0).astype(np.float32)
+
+
+@pytest.fixture
+def persistent_cache(tmp_path, monkeypatch):
+    """JAX's persistent cache turned on the way an operator places it —
+    ``JAX_COMPILATION_CACHE_DIR`` — at a test-owned directory, never the
+    checkout's; the process-global JAX config is restored afterwards."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    keys = ("jax_enable_compilation_cache", "jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    cache = str(tmp_path / "ccache")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", cache)
+    assert compilecache.enable_persistent_cache() == cache
+    yield cache
+    for k, v in saved.items():
+        jax.config.update(k, v)
+    compilation_cache.reset_cache()
 
 
 def fresh(cache_entries=0, **engine_kw):
@@ -473,8 +496,7 @@ def test_warmup_on_load_runs_in_background():
 # ---------------------------------------------------------------------------
 # persistence: warm-restart zero-recompile round trip
 # ---------------------------------------------------------------------------
-def test_warm_restart_replays_index_and_absorbs_requests(tmp_path):
-    cache_dir = str(tmp_path / "ccache")
+def test_warm_restart_replays_index_and_absorbs_requests(persistent_cache):
 
     def serve_one(eng):
         ac = AlchemistContext(engine=eng)
@@ -487,8 +509,9 @@ def test_warm_restart_replays_index_and_absorbs_requests(tmp_path):
             ac.stop()
 
     # cold engine: the request-path compile lands in the index
-    eng1 = fresh(compile_cache_dir=cache_dir, bucketing=True)
+    eng1 = fresh(bucketing=True)
     try:
+        assert eng1.compile_cache_dir == persistent_cache
         out1 = serve_one(eng1)
         assert eng1.compile_log.stats()["request_compiles"] == 1
         assert len(eng1._exec_index) >= 1
@@ -497,7 +520,7 @@ def test_warm_restart_replays_index_and_absorbs_requests(tmp_path):
 
     # restarted engine, same dir: warmup replays the index; the same
     # tenant traffic then sees ZERO request-path compiles
-    eng2 = fresh(compile_cache_dir=cache_dir, bucketing=True)
+    eng2 = fresh(bucketing=True)
     try:
         stats = eng2.warmup()
         assert stats["replayed"] >= 1
@@ -581,7 +604,19 @@ def test_session_bucketing_override_vs_engine_default():
 # ---------------------------------------------------------------------------
 # configure wire surface
 # ---------------------------------------------------------------------------
-def test_configure_echoes_bucketing_and_cache_dir(tmp_path):
+def _configure_raw(engine, session, options):
+    """A configure request as any wire client could send it, bypassing
+    the typed client signature."""
+    from repro.core import protocol
+
+    return protocol.decode_result(engine.configure(
+        protocol.encode_configure(protocol.Configure(
+            session=session, options=options))))
+
+
+def test_configure_echoes_bucketing_and_cache_dir(persistent_cache):
+    """The engine keeps its index in the one cache directory the process
+    resolved; no configure option can repoint it."""
     engine = fresh()
     ac = AlchemistContext(engine=engine)
     try:
@@ -589,10 +624,12 @@ def test_configure_echoes_bucketing_and_cache_dir(tmp_path):
         assert eff["bucketing"] is False
         eff = ac.configure(bucketing=True)
         assert eff["bucketing"] is True
-        cache_dir = str(tmp_path / "cc")
-        eff = ac.configure(cache_dir=cache_dir)
-        assert eff["cache_dir"] == cache_dir
-        assert engine.compile_cache_dir == cache_dir
+        assert "cache_dir" not in eff
+        assert engine.compile_cache_dir == persistent_cache
+        res = _configure_raw(engine, ac.session, {"cache_dir": "/elsewhere"})
+        assert "unknown configure option" in res.error
+        assert "cache_dir" in res.error
+        assert engine.compile_cache_dir == persistent_cache
     finally:
         ac.stop()
         engine.shutdown()
@@ -622,8 +659,10 @@ def test_configure_rejects_bad_options_without_mutating():
             ac.configure(warmup=[0])
         with pytest.raises(AlchemistError, match="warmup"):
             ac.configure(warmup="now")
-        with pytest.raises(AlchemistError, match="cache_dir"):
+        with pytest.raises(TypeError, match="cache_dir"):
             ac.configure(cache_dir=7)
+        assert "cache_dir" in _configure_raw(
+            engine, ac.session, {"cache_dir": 7}).error
         sess = engine.session(ac.session)
         assert sess.bucketing is None        # nothing half-applied
         assert engine.compile_cache_dir is None
@@ -645,4 +684,30 @@ def test_compile_stats_builtin_over_the_wire():
         assert "program_caches" in stats["engine"]
     finally:
         ac.stop()
+        engine.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# the one cache-directory rule
+# ---------------------------------------------------------------------------
+def test_cache_dir_is_the_environment_variable_when_set(monkeypatch,
+                                                        tmp_path):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "x"))
+    assert compilecache.cache_dir() == str(tmp_path / "x")
+
+
+def test_cache_dir_is_the_fixed_checkout_path_when_unset(monkeypatch):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert compilecache.cache_dir() == os.path.join(root, ".jax_cache")
+    # fixed: the same path on every call, in every process
+    assert compilecache.cache_dir() == compilecache.DEFAULT_CACHE_DIR
+
+
+def test_engine_without_a_persistent_cache_keeps_no_index():
+    engine = fresh()
+    try:
+        assert engine.compile_cache_dir is None
+        assert engine.compile_stats()["executable_index"] == 0
+    finally:
         engine.shutdown()
