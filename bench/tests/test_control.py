@@ -1,0 +1,76 @@
+"""The controls at a size a test run holds: the reference put in the
+program's place with its products one precision step down (``high``,
+bfloat16 three-pass) goes through the cell's own comparison and reads
+``correct`` false, where the program's run, held to the same limits,
+reads true.
+
+The limits here are the committed ones scaled to the test's size. At the
+cells' sizes the chip separates program and control by 88x or more, and
+the committed limits sit between them (PERF.md). At this size the two
+lie 2x to 6x apart on this seed, so each limit is set between the two
+readings on the CPU by the same rule: ``s_rel`` program 8.7e-8, control
+5.0e-7; ``u_dist`` 3.4e-7 and 1.9e-6; ``v_dist`` 1.2e-6 and 1.3e-6, not
+separated, so held above the program only; ``cg.residual`` 3.4e-6 and
+2.1e-5."""
+import json
+import os
+
+import pytest
+
+import tiny
+from harness.main import within
+from harness.spec import load_module
+
+SEED = 2_500_000_003
+ITERS = 400
+LIMITS = {"ocean": {"svd.s_rel": 2e-7, "svd.v_dist": 3e-6,
+                    "svd.u_dist": 8e-7},
+          "speech": {"cg.residual": 8e-6}}
+
+
+@pytest.fixture(scope="module")
+def root(tmp_path_factory):
+    """The tiny copy, with a speech problem large enough and solved long
+    enough that full precision converges past where ``high`` stalls (at
+    100 steps of the tiniest problem both stop short of their floors),
+    and the limits at this size."""
+    root = tiny.copy(str(tmp_path_factory.mktemp("bench")))
+    bench = os.path.join(root, "bench")
+    tiny.edit_json(os.path.join(bench, "configs", "speech.json"),
+                   rows=4096, feats=32, classes=8, rf_dim=512,
+                   limits=LIMITS["speech"])
+    tiny.edit_json(os.path.join(bench, "configs", "ocean.json"),
+                   limits=LIMITS["ocean"])
+    tiny.edit_json(os.path.join(bench, "traffic", "cg.json"),
+                   requests=[{"kind": "cg_job", "share": 1.0,
+                              "max_iters": ITERS, "tol": 0.0}])
+    return root
+
+
+@pytest.fixture(scope="module")
+def control(root):
+    return load_module(os.path.join(root, "bench", "control.py"))
+
+
+def cfg(root, name):
+    with open(os.path.join(root, "bench", "configs", name + ".json")) as f:
+        return json.load(f)
+
+
+def program_is_correct(root, cell):
+    rc, out, err = tiny.run(root, cell, seed=SEED)
+    assert rc == 0, err
+    res = tiny.result(out)
+    assert res["correct"], err
+    return res["checks"]
+
+
+@pytest.mark.parametrize("cell,config,kind", [
+    ("ocean.svd", "ocean", "svd"), ("speech.cg", "speech", "cg_job")])
+def test_control_is_not_correct(root, control, cell, config, kind):
+    extra = (ITERS,) if kind == "cg_job" else ()
+    checks = control.CHECKS[kind](cfg(root, config), SEED, *extra)
+    assert not within(checks), checks
+    prog = program_is_correct(root, cell)
+    assert set(prog) == set(checks)
+    assert all(prog[k]["limit"] == checks[k]["limit"] for k in checks)
