@@ -92,6 +92,8 @@ LOCK_RANKS: dict[str, int] = {
     "costmodel.compile": 43,
     "costmodel.cache": 44,
     "costmodel.qos": 45,
+    # fed by JAX's compile events, which fire under any of the above
+    "costmodel.jit": 46,
 }
 
 

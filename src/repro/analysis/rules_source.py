@@ -7,7 +7,9 @@
   chain). A ``block_until_ready`` / ``np.asarray`` / ``print`` inside a
   trace either silently bakes a host round trip into every dispatch or
   fails only at fuse time on the request path — both are bugs that
-  survive eager testing.
+  survive eager testing. A host span (``tracing.span``,
+  ``TraceAnnotation``) is banned there too: it would time the trace,
+  once, and never a run.
 * **PKL001** no-pickle-on-wire — the user-data modules
   (``wire``/``transfer``/``protocol``/``server``) must never import or
   call ``pickle``-family deserializers (or ``eval``/``exec``). The
@@ -63,6 +65,7 @@ def _kernel_files() -> list[str]:
 #: attribute calls that force a device->host sync or do I/O
 _BANNED_METHOD_CALLS = frozenset({
     "block_until_ready", "tolist", "item", "acquire", "release",
+    "TraceAnnotation",
 })
 #: bare-name calls that are host-side I/O
 _BANNED_NAME_CALLS = frozenset({"print", "open", "input"})
@@ -75,6 +78,7 @@ _BANNED_MODULE_CALLS = {
     "threading": None,          # any attribute
     "os": None,
     "socket": None,
+    "tracing": None,            # host spans (repro.core.tracing)
 }
 
 
@@ -137,10 +141,10 @@ def _impure_nodes(fndef: ast.AST) -> Iterable[tuple[int, str]]:
         elif isinstance(fn, ast.Attribute):
             if fn.attr in _BANNED_METHOD_CALLS:
                 yield node.lineno, f".{fn.attr}()"
-            elif isinstance(fn.value, ast.Name):
-                banned = _BANNED_MODULE_CALLS.get(fn.value.id)
-                if banned is not None and (not banned
-                                           or fn.attr in banned):
+            elif isinstance(fn.value, ast.Name) \
+                    and fn.value.id in _BANNED_MODULE_CALLS:
+                banned = _BANNED_MODULE_CALLS[fn.value.id]
+                if banned is None or fn.attr in banned:
                     yield node.lineno, f"{fn.value.id}.{fn.attr}()"
 
 

@@ -46,6 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.analysis import locktrace
+from repro.core import tracing
 
 from repro.core.backends import base
 from repro.core.backends.base import REPLICATED, ROWBLOCK
@@ -309,10 +310,12 @@ def _truncated_svd(A, k: int, oversample: int = 32, max_iters: int = 0,
         # each Lanczos iteration re-enters here: the natural QoS
         # preemption boundary for the reverse-communication driver
         base.yield_check()
-        return np.asarray(_gram_matvec(x, jnp.asarray(q, x.dtype)),
-                          np.float64)
+        with tracing.span(tracing.LANCZOS_MATVEC):
+            return np.asarray(_gram_matvec(x, jnp.asarray(q, x.dtype)),
+                              np.float64)
 
-    sigma, V, iters, matvecs = _lanczos_gram(matvec, d, k, m, q0)
+    with tracing.span(tracing.LANCZOS):
+        sigma, V, iters, matvecs = _lanczos_gram(matvec, d, k, m, q0)
     v_dev = jnp.asarray(V, x.dtype)
     U = (x @ v_dev) / jnp.maximum(jnp.asarray(sigma, x.dtype), 1e-30)
     return {"U": U, "S": jnp.asarray(sigma, jnp.float32), "V": v_dev,
@@ -432,56 +435,61 @@ def _cg_residual(x, y, lam_n, b_norm, w):
 def _cg_solve(X, Y, lam: float = 1e-5, rf_dim: int = 0,
               bandwidth: float = 1.0, max_iters: int = 200,
               tol: float = 1e-8, seed: int = 0, use_pallas: bool = False):
-    x = X
-    if rf_dim:
-        x = _rf_expand(x, rf_dim, bandwidth=bandwidth, seed=seed,
-                       use_pallas=use_pallas)
-    y = Y
-    n, d = x.shape
-    lam_n = jnp.asarray(n * lam, x.dtype)
+    with tracing.span(tracing.CG):
+        x = X
+        if rf_dim:
+            with tracing.span(tracing.CG_RF_MAP):
+                x = _rf_expand(x, rf_dim, bandwidth=bandwidth, seed=seed,
+                               use_pallas=use_pallas)
+        y = Y
+        n, d = x.shape
+        lam_n = jnp.asarray(n * lam, x.dtype)
 
-    b = _cg_rhs(x, y)                            # (d, c) rhs
-    b_norm = jnp.linalg.norm(b, axis=0)
-    w = jnp.zeros(b.shape, x.dtype)
-    r = b
-    p = r
-    rs = jnp.sum(r * r, axis=0)
+        with tracing.span(tracing.CG_RHS):
+            b = _cg_rhs(x, y)                        # (d, c) rhs
+            b_norm = jnp.linalg.norm(b, axis=0)
+            w = jnp.zeros(b.shape, x.dtype)
+            r = b
+            p = r
+            rs = jnp.sum(r * r, axis=0)
 
-    _step = jax.jit(lambda x, lam_n, st: _cg_step(x, lam_n, st,
-                                                  use_pallas=use_pallas))
+        _step = jax.jit(lambda x, lam_n, st: _cg_step(x, lam_n, st,
+                                                      use_pallas=use_pallas))
 
-    iters = 0
-    rel = float(jnp.max(jnp.sqrt(rs) / jnp.maximum(b_norm, 1e-30)))
-    history = [rel]
-    state = (w, r, p, rs)
-    while iters < max_iters and rel > tol:
-        base.yield_check()          # QoS iteration boundary
-        state = _step(x, lam_n, state)
-        iters += 1
-        rel = float(jnp.max(jnp.sqrt(state[3])
-                            / jnp.maximum(b_norm, 1e-30)))
-        history.append(rel)
-    # in float32 the recurrence drifts from the true residual: on a v5e
-    # it fell to 7e-8 while b - A w stalled at 2e-5. The loop stops on
-    # the recurrence; what is reported is the true residual of the W
-    # returned, one more pass over the rows.
-    true_rel = float(_cg_residual(x, y.astype(x.dtype), lam_n, b_norm,
-                                  state[0]))
+        iters = 0
+        rel = float(jnp.max(jnp.sqrt(rs) / jnp.maximum(b_norm, 1e-30)))
+        history = [rel]
+        state = (w, r, p, rs)
+        while iters < max_iters and rel > tol:
+            base.yield_check()          # QoS iteration boundary
+            with tracing.span(tracing.CG_STEP):
+                state = _step(x, lam_n, state)
+                iters += 1
+                rel = float(jnp.max(jnp.sqrt(state[3])
+                                    / jnp.maximum(b_norm, 1e-30)))
+            history.append(rel)
+        # in float32 the recurrence drifts from the true residual: on a
+        # v5e it fell to 7e-8 while b - A w stalled at 2e-5. The loop
+        # stops on the recurrence; what is reported is the true residual
+        # of the W returned, one more pass over the rows.
+        with tracing.span(tracing.CG_RESIDUAL):
+            true_rel = float(_cg_residual(x, y.astype(x.dtype), lam_n,
+                                          b_norm, state[0]))
 
-    return {
-        "W": state[0],
-        "iterations": iters,
-        "relative_residual": true_rel,
-        "residual_history": [float(h) for h in history],
-        "expanded_dim": int(d),
-        # which implementation ran each stage: a requested kernel that
-        # gave way to the jnp reference by shape shows here
-        "kernels": {
-            "rf_map": ("pallas" if use_pallas else "jnp") if rf_dim
-            else "none",
-            "normal_matvec": "pallas" if nm_ops.uses_kernel(d, use_pallas)
-            else "jnp"},
-    }
+        return {
+            "W": state[0],
+            "iterations": iters,
+            "relative_residual": true_rel,
+            "residual_history": [float(h) for h in history],
+            "expanded_dim": int(d),
+            # which implementation ran each stage: a requested kernel
+            # that gave way to the jnp reference by shape shows here
+            "kernels": {
+                "rf_map": ("pallas" if use_pallas else "jnp") if rf_dim
+                else "none",
+                "normal_matvec": "pallas"
+                if nm_ops.uses_kernel(d, use_pallas) else "jnp"},
+        }
 
 
 @register("skylark", "nmf", accepts=_DENSE)

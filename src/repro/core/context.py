@@ -49,7 +49,7 @@ import types
 import weakref
 from typing import Any, Optional
 
-from repro.core import protocol, transfer, wire
+from repro.core import protocol, tracing, transfer, wire
 from repro.core.engine import ENGINE_LIBRARY, AlchemistEngine, \
     make_engine_mesh
 from repro.core.expr import AlchemistBusyError, AlchemistError, AlFuture, \
@@ -314,21 +314,22 @@ class AlchemistContext:
         when it sends one; exhaustion raises the typed
         :class:`AlchemistBusyError` carrying the last hint."""
         self._check_alive()
-        payload = protocol.encode_command(protocol.Command(
-            library=library, routine=routine, args=args,
-            session=self.session))
-        delay = _BUSY_BACKOFF_S
-        for attempt in range(self.busy_retries + 1):
-            sub = protocol.decode_result(self.engine.submit(payload))
-            if not (sub.error
-                    and sub.error.startswith("AlchemistBusyError")):
-                break
-            if attempt == self.busy_retries:
-                break
-            hint = sub.retry_after_s
-            time.sleep(min(hint if hint > 0 else delay,
-                           _BUSY_BACKOFF_CAP_S))
-            delay = min(delay * 2, _BUSY_BACKOFF_CAP_S)
+        with tracing.span(tracing.CLIENT_SUBMIT, session=self.session):
+            payload = protocol.encode_command(protocol.Command(
+                library=library, routine=routine, args=args,
+                session=self.session))
+            delay = _BUSY_BACKOFF_S
+            for attempt in range(self.busy_retries + 1):
+                sub = protocol.decode_result(self.engine.submit(payload))
+                if not (sub.error
+                        and sub.error.startswith("AlchemistBusyError")):
+                    break
+                if attempt == self.busy_retries:
+                    break
+                hint = sub.retry_after_s
+                time.sleep(min(hint if hint > 0 else delay,
+                               _BUSY_BACKOFF_CAP_S))
+                delay = min(delay * 2, _BUSY_BACKOFF_CAP_S)
         if sub.error:
             if sub.error.startswith("AlchemistBusyError"):
                 _, _, msg = sub.error.partition(": ")

@@ -17,7 +17,9 @@ calibration error is always visible.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
+import time
 
 from repro.analysis import locktrace
 
@@ -356,7 +358,13 @@ def percentile(values, q: float) -> float:
 class TaskRecord:
     """Accounting for one scheduled command: which session ran what, how
     long it waited in the queue (dependencies + worker availability) vs
-    how long it executed, and its terminal state.
+    how long its task body ran, and its terminal state.
+
+    ``exec_s`` ends when the task body returns. JAX dispatches device
+    work asynchronously, so a routine's last dispatched programs may
+    still be running then (the ``U = X V`` product of a truncated SVD):
+    ``exec_s`` is host time to the return, not time to a finished
+    result.
 
     Backend-ABI fields: ``fused_ops`` is how many logical commands this
     task executed (1 normally; N for the lead task of a fused chain);
@@ -478,14 +486,22 @@ class CompileRecord:
 
 
 class CompileLog:
-    """Compile-latency accounting — the observability half of the
-    compile cache. Where TaskLog shows queue-vs-execute time, this log
-    shows the third hidden term the paper's overhead argument warns
-    about: XLA trace+compile seconds, and *where* they were paid (on a
+    """Compile-latency accounting for the engine's plans — the
+    observability half of the compile cache. Where TaskLog shows
+    queue-vs-execute time, this log shows the third hidden term the
+    paper's overhead argument warns about: XLA trace+compile seconds of
+    the programs the engine plans, and *where* they were paid (on a
     tenant's first call, or off-path during warmup). The smoke gate in
     ``benchmarks/compile_warmup.py`` asserts directly on
     :meth:`stats`: after warmup, ``request_compiles`` for bucketed
-    shapes must be zero."""
+    shapes must be zero.
+
+    ``compiles`` counts only those plan compiles. A ``jax.jit`` that a
+    routine body builds or calls itself (the host loops' matvec and CG
+    step) never passes through the plan, so it is not here: the
+    process-wide :data:`JIT_LOG` counts every executable JAX obtains,
+    every persistent-cache load and every trace, and
+    ``engine.compile_stats()["jit"]`` reports it."""
 
     def __init__(self):
         self.records: list[CompileRecord] = []
@@ -544,6 +560,91 @@ class CompileLog:
     def sessions(self) -> list[int]:
         with self._lock:
             return sorted({r.session for r in self.records})
+
+
+#: JAX's own compile-path monitoring events, by the key the jit counter
+#: counts each under: an executable obtained (compiled by XLA or supplied
+#: by the persistent cache), a load from the persistent cache, a trace
+#: of a Python function to a jaxpr (nested traces count too)
+JIT_EVENTS = {
+    "/jax/core/compile/backend_compile_duration": "executables",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_loads",
+    "/jax/core/compile/jaxpr_trace_duration": "traces",
+}
+
+
+#: how many of the latest jit events keep their time, for windows
+JIT_EVENTS_KEPT = 4096
+
+
+class JitLog:
+    """Every executable, persistent-cache load and trace in this process,
+    counted by function name from JAX's own monitoring events — so it
+    sees the jits a routine body builds per call, which
+    :class:`CompileLog` does not.
+
+    The listener is process-wide (JAX has one registry), so there is one
+    log, :data:`JIT_LOG`, whatever the number of engines; ``install`` is
+    idempotent. The latest :data:`JIT_EVENTS_KEPT` events also keep
+    their ``time.perf_counter()`` time, so :meth:`events` can say what
+    happened inside a window. A persistent-cache load carries no
+    function name."""
+
+    def __init__(self):
+        self._counts: collections.Counter = collections.Counter()
+        self._events: collections.deque = collections.deque(
+            maxlen=JIT_EVENTS_KEPT)
+        self._dropped_until: float | None = None   # the latest dropped
+        self._installed = False
+        self._lock = locktrace.make_lock("costmodel.jit")
+
+    def install(self) -> None:
+        with self._lock:
+            if self._installed:
+                return
+            self._installed = True
+        import jax.monitoring
+
+        jax.monitoring.register_event_duration_secs_listener(self._on_event)
+
+    def _on_event(self, event: str, duration_secs: float, **kwargs) -> None:
+        key = JIT_EVENTS.get(event)
+        if key is None:
+            return
+        fun = str(kwargs.get("fun_name", ""))
+        t = time.perf_counter()
+        with self._lock:
+            self._counts[key, fun] += 1
+            if len(self._events) == self._events.maxlen:
+                self._dropped_until = self._events[0][0]
+            self._events.append((t, key, fun))
+
+    def stats(self) -> dict:
+        """Totals per key, and per function name."""
+        with self._lock:
+            counts = dict(self._counts)
+        out: dict = {key: 0 for key in JIT_EVENTS.values()}
+        by_function: dict = {}
+        for (key, fun), n in counts.items():
+            out[key] += n
+            by_function.setdefault(fun, {})[key] = n
+        out["by_function"] = by_function
+        return out
+
+    def events(self, since: float = float("-inf"),
+               until: float = float("inf")) -> list[tuple] | None:
+        """``(time, key, function name)`` of the events whose
+        ``perf_counter`` time lies in ``[since, until)``; ``None`` when an
+        event dropped from the latest :data:`JIT_EVENTS_KEPT` may have
+        lain there, so the list could not be whole."""
+        with self._lock:
+            if self._dropped_until is not None \
+                    and since <= self._dropped_until:
+                return None
+            return [e for e in self._events if since <= e[0] < until]
+
+
+JIT_LOG = JitLog()
 
 
 # ---- QoS price model (fair-share virtual time, see core/qos/) ----

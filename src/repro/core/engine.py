@@ -74,8 +74,8 @@ from repro.core import backends as backend_registry
 from repro.core import cache as caching, compilecache, configopts, \
     protocol, scheduler as scheduling
 from repro.core.backends import base as backend_base
-from repro.core.costmodel import CacheLog, CompileLog, QosLog, TaskLog, \
-    TransferLog, routine_price_seconds
+from repro.core.costmodel import JIT_LOG, CacheLog, CompileLog, QosLog, \
+    TaskLog, TransferLog, routine_price_seconds
 from repro.core.qos import QUOTA_KEYS, AdmissionController, FairShareQueue, \
     QuotaConfig
 from repro.core.handles import BLOCK2D, LAYOUTS, REPLICATED, ROWBLOCK, \
@@ -317,6 +317,8 @@ class AlchemistEngine:
             else compilecache.DEFAULT_WARMUP_GRID
         self.warmup_on_load = bool(warmup_on_load)
         self.compile_log = CompileLog()
+        # every jit of the process, the routines' own included
+        JIT_LOG.install()
         # the executable index lives beside JAX's persistent cache
         # (None when the process never turned the cache on)
         self.compile_cache_dir: Optional[str] = \
@@ -915,8 +917,16 @@ class AlchemistEngine:
         """Engine-wide compile accounting: the CompileLog summary plus
         each backend's live program-cache occupancy/evictions and the
         executable-index size — what benchmarks and session stats
-        surface."""
+        surface.
+
+        ``compiles``, ``hits`` and the keys beside them count the
+        engine's plan compiles only. ``jit`` counts, for the whole
+        process, every executable JAX obtained (``executables``), every
+        load from the persistent cache (``cache_loads``) and every trace
+        (``traces``), also per function name (``by_function``): the
+        jits that routine bodies build and run themselves show there."""
         out = self.compile_log.stats()
+        out["jit"] = JIT_LOG.stats()
         out["executable_index"] = len(self._exec_index) \
             if self._exec_index is not None else 0
         out["program_caches"] = {

@@ -46,7 +46,7 @@ from typing import TYPE_CHECKING, Any, Optional, Union
 
 import numpy as np
 
-from repro.core import protocol
+from repro.core import protocol, tracing
 from repro.core.handles import MatrixHandle
 from repro.core.libraries import spec as specs
 
@@ -154,7 +154,9 @@ class AlFuture:
         self._check_not_orphaned()
         if self._result is None:
             self.ac._check_alive()
-            self._result = self.ac._task_op(protocol.WAIT, self.task)
+            with tracing.span(tracing.CLIENT_WAIT, task=self.task,
+                              session=self.ac.session):
+                self._result = self.ac._task_op(protocol.WAIT, self.task)
         res = self._result
         if res.error:
             raise AlchemistError(res.error)

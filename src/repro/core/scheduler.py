@@ -50,6 +50,7 @@ import time
 from typing import Any, Callable, Iterable, Optional
 
 from repro.analysis import locktrace, statemachine
+from repro.core import tracing
 from repro.core.qos.policy import FifoReadyQueue
 
 QUEUED = "QUEUED"
@@ -75,7 +76,9 @@ class Task:
     becomes runnable at zero. ``data_deps`` names producer tasks whose
     failure must propagate here (deferred-handle edges only).
     ``wait_s``/``exec_s`` split the task's latency into time spent queued
-    behind dependencies and worker availability vs time actually running.
+    behind dependencies and worker availability vs time its body ran.
+    ``exec_s`` ends when ``fn`` returns, which may be before the device
+    work it dispatched completes (JAX dispatches asynchronously).
     """
     id: int
     session: int
@@ -537,7 +540,9 @@ class TaskScheduler:
                              f"{failed[1]}")
                 continue
             try:
-                result = task.fn(task)
+                with tracing.span(tracing.TASK, task=task.id,
+                                  session=task.session):
+                    result = task.fn(task)
             except TaskFailure as e:
                 self._finish(task, FAILED, e.payload, str(e))
             except Exception as e:  # total barrier: a crashing task body

@@ -42,7 +42,7 @@ import msgpack
 import numpy as np
 
 from repro.analysis import locktrace, statemachine
-from repro.core import compilecache, protocol, transfer, wire
+from repro.core import compilecache, protocol, tracing, transfer, wire
 from repro.core.costmodel import WireLog
 from repro.core.engine import SYSTEM_SESSION, AlchemistEngine, \
     make_engine_mesh
@@ -203,6 +203,9 @@ class _Connection:
     # sending a reply-role frame) is refused below — one source of
     # truth with wire.FRAME_TYPES and the client's expected-reply sets
     _ENDPOINTS = wire.REQUEST_ENDPOINTS
+    #: request frame code -> the span around its handling
+    _SPANS = {spec.code: tracing.server_frame(spec.name)
+              for spec in wire.FRAME_SPECS if spec.role == "request"}
 
     def _dispatch(self, frame_type: int, payload: bytes) -> None:
         endpoint = self._ENDPOINTS.get(frame_type)
@@ -211,38 +214,39 @@ class _Connection:
                 wire.UnknownFrameType(
                     f"frame 0x{frame_type:02x} is not a request")))
             return
-        self.server.wire_log.record(
-            endpoint, frames_in=1,
-            bytes_in=wire.HEADER_BYTES + len(payload))
-        if frame_type == wire.FRAME_HANDSHAKE:
-            self._do_handshake(payload)
-        elif frame_type == wire.FRAME_FREE:
-            self._do_free(payload)
-        elif frame_type == wire.FRAME_ALIAS_LOOKUP:
-            self._do_alias_lookup(payload,
-                                  wire.HEADER_BYTES + len(payload))
-        elif frame_type == wire.FRAME_UPLOAD_BEGIN:
-            self._do_upload_begin(payload,
-                                  wire.HEADER_BYTES + len(payload))
-        elif frame_type == wire.FRAME_UPLOAD_CHUNK:
-            self._do_upload_chunk(payload,
-                                  wire.HEADER_BYTES + len(payload))
-        elif frame_type == wire.FRAME_UPLOAD_COMMIT:
-            self._do_upload_commit(payload,
-                                   wire.HEADER_BYTES + len(payload))
-        elif frame_type == wire.FRAME_FETCH:
-            self._do_fetch(payload)
-        else:
-            # the byte-level engine endpoints: same bytes in, same bytes
-            # out as the in-memory bridge — the engine itself counts the
-            # logical crossing in endpoint_counts
-            try:
-                reply = getattr(self.engine, endpoint)(payload)
-            except Exception as e:
-                reply = _error_result(0, e)
-            self._send_result(
-                endpoint, reply,
-                allow_throttle=(frame_type == wire.FRAME_COMMAND))
+        with tracing.span(self._SPANS[frame_type]):
+            self.server.wire_log.record(
+                endpoint, frames_in=1,
+                bytes_in=wire.HEADER_BYTES + len(payload))
+            if frame_type == wire.FRAME_HANDSHAKE:
+                self._do_handshake(payload)
+            elif frame_type == wire.FRAME_FREE:
+                self._do_free(payload)
+            elif frame_type == wire.FRAME_ALIAS_LOOKUP:
+                self._do_alias_lookup(payload,
+                                      wire.HEADER_BYTES + len(payload))
+            elif frame_type == wire.FRAME_UPLOAD_BEGIN:
+                self._do_upload_begin(payload,
+                                      wire.HEADER_BYTES + len(payload))
+            elif frame_type == wire.FRAME_UPLOAD_CHUNK:
+                self._do_upload_chunk(payload,
+                                      wire.HEADER_BYTES + len(payload))
+            elif frame_type == wire.FRAME_UPLOAD_COMMIT:
+                self._do_upload_commit(payload,
+                                       wire.HEADER_BYTES + len(payload))
+            elif frame_type == wire.FRAME_FETCH:
+                self._do_fetch(payload)
+            else:
+                # the byte-level engine endpoints: same bytes in, same
+                # bytes out as the in-memory bridge — the engine itself
+                # counts the logical crossing in endpoint_counts
+                try:
+                    reply = getattr(self.engine, endpoint)(payload)
+                except Exception as e:
+                    reply = _error_result(0, e)
+                self._send_result(
+                    endpoint, reply,
+                    allow_throttle=(frame_type == wire.FRAME_COMMAND))
 
     def _do_handshake(self, payload: bytes) -> None:
         try:
@@ -396,17 +400,19 @@ class _Connection:
                 raise RuntimeError(f"upload failed mid-stream: {up.error}")
             session = up.session
             up.wire_bytes += frame_len
-            if not up.pieces:
-                host = np.zeros(up.shape, dtype=np.dtype(up.dtype))
-            elif len(up.pieces) == 1:
-                host = up.pieces[0]
-            else:
-                host = np.concatenate(up.pieces, axis=0)
-            arr = jax.device_put(
-                host, self.engine.dist_sharding(up.shape))
-            handle = self.engine.put(
-                arr, name=up.name, session=session,
-                fingerprint=d.get("fingerprint"))
+            with tracing.span(tracing.SERVER_ASSEMBLE, session=session):
+                if not up.pieces:
+                    host = np.zeros(up.shape, dtype=np.dtype(up.dtype))
+                elif len(up.pieces) == 1:
+                    host = up.pieces[0]
+                else:
+                    host = np.concatenate(up.pieces, axis=0)
+            with tracing.span(tracing.SERVER_DEVICE_PUT, session=session):
+                arr = jax.device_put(
+                    host, self.engine.dist_sharding(up.shape))
+                handle = self.engine.put(
+                    arr, name=up.name, session=session,
+                    fingerprint=d.get("fingerprint"))
             if up.single:
                 # whole-matrix single-shot send: one plain record, like
                 # the in-memory non-streamed path (records the device

@@ -54,7 +54,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import cache as caching
+from repro.core import cache as caching, tracing
 from repro.core.costmodel import (
     TransferRecord,
     reshard_transfer_seconds,
@@ -159,9 +159,10 @@ def to_engine(engine: AlchemistEngine, matrix, name: Optional[str] = None,
     ``wire_nbytes``.
     """
     if not isinstance(engine, AlchemistEngine):
-        return _to_engine_bridge(engine, matrix, name=name,
-                                 session=session, chunk_rows=chunk_rows,
-                                 dedup=dedup)
+        with tracing.span(tracing.CLIENT_UPLOAD, session=session):
+            return _to_engine_bridge(engine, matrix, name=name,
+                                     session=session, chunk_rows=chunk_rows,
+                                     dedup=dedup)
     if isinstance(matrix, jax.Array):
         arr = jax.device_put(matrix, engine.dist_sharding(matrix.shape))
         rec = engine.transfer_log.record(arr.nbytes, "to_engine",
@@ -329,11 +330,12 @@ def _to_engine_bridge(bridge, matrix, name: Optional[str],
         pieces = (matrix.rdd.partition(i)
                   for i in range(matrix.rdd.num_partitions)) \
             if is_rm else (src[lo:hi] for lo, hi in plan)
-        for piece in pieces:
-            piece = np.asarray(piece)
-            hasher.update(piece)
-            logical += piece.nbytes
-        fingerprint = hasher.fingerprint()
+        with tracing.span(tracing.CLIENT_HASH, session=session):
+            for piece in pieces:
+                piece = np.asarray(piece)
+                hasher.update(piece)
+                logical += piece.nbytes
+            fingerprint = hasher.fingerprint()
         hit = bridge.alias_lookup(fingerprint, shape, session, name,
                                   logical, num_chunks)
         if hit is not None:
@@ -375,8 +377,11 @@ def to_client(engine: AlchemistEngine, handle: MatrixHandle,
     as FETCH frames and land in the same per-partition blocks.
     """
     if not isinstance(engine, AlchemistEngine):
-        return _to_client_bridge(engine, handle, num_partitions,
-                                 session=session, chunk_rows=chunk_rows)
+        with tracing.span(tracing.CLIENT_FETCH,
+                          session=SYSTEM_SESSION if session is None
+                          else session):
+            return _to_client_bridge(engine, handle, num_partitions,
+                                     session=session, chunk_rows=chunk_rows)
     arr = engine.get(handle, session=session)
     sess = SYSTEM_SESSION if session is None else session
     if arr.ndim < 1 or arr.shape[0] == 0:

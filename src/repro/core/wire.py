@@ -49,7 +49,7 @@ import msgpack
 import numpy as np
 
 from repro.analysis import locktrace
-from repro.core import protocol
+from repro.core import protocol, tracing
 from repro.core.costmodel import TransferRecord, WireLog
 
 MAGIC = b"ALCH"
@@ -514,8 +514,9 @@ class SocketBridge:
             "session": session, "name": name,
             "logical_nbytes": int(logical_nbytes),
             "num_chunks": int(num_chunks)})
-        res = protocol.decode_result(
-            self._rpc("alias_lookup", FRAME_ALIAS_LOOKUP, payload))
+        with tracing.span(tracing.CLIENT_ALIAS_LOOKUP, session=session):
+            res = protocol.decode_result(
+                self._rpc("alias_lookup", FRAME_ALIAS_LOOKUP, payload))
         if res.error:
             raise _rebuild_engine_error(res.error)
         if not res.values.get("hit"):
@@ -548,14 +549,17 @@ class SocketBridge:
             res = protocol.decode_result(reply)
             raise_engine_error(res)
             upload_id = res.values["upload"]
-            for seq, chunk in enumerate(chunks):
-                self._send("upload", FRAME_UPLOAD_CHUNK, msgpack.packb({
-                    "upload": upload_id, "seq": seq,
-                    "array": pack_ndarray(chunk)}))
-            fp = fingerprint() if callable(fingerprint) else fingerprint
-            self._send("upload", FRAME_UPLOAD_COMMIT, msgpack.packb({
-                "upload": upload_id, "fingerprint": fp}))
-            ftype, reply = self._recv("upload")
+            with tracing.span(tracing.CLIENT_STREAM, session=session):
+                for seq, chunk in enumerate(chunks):
+                    self._send("upload", FRAME_UPLOAD_CHUNK, msgpack.packb({
+                        "upload": upload_id, "seq": seq,
+                        "array": pack_ndarray(chunk)}))
+            with tracing.span(tracing.CLIENT_COMMIT, session=session):
+                fp = fingerprint() if callable(fingerprint) \
+                    else fingerprint
+                self._send("upload", FRAME_UPLOAD_COMMIT, msgpack.packb({
+                    "upload": upload_id, "fingerprint": fp}))
+                ftype, reply = self._recv("upload")
         res = protocol.decode_result(reply)
         raise_engine_error(res)
         return (res.values["handle"],
