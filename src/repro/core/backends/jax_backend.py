@@ -45,6 +45,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import kernels
 from repro.analysis import locktrace
 from repro.core import tracing
 
@@ -286,10 +287,11 @@ def _qr(A):
     return {"Q": q, "R": r}
 
 
-@jax.jit
-def _gram_matvec(x, v):
-    """v -> X^T (X v); never materializes X^T X."""
-    return x.T @ (x @ v)
+@functools.partial(jax.jit, static_argnames="path")
+def _gram_matvec(x, v, path: str = "xla"):
+    """v -> X^T (X v); never materializes X^T X. ``path`` is
+    ``nm_ops.gram_path``'s: the one-pass kernel, or XLA's two passes."""
+    return nm_ops.gram_matvec(x, v, path=path)
 
 
 @register("elemental", "truncated_svd", accepts=_DENSE)
@@ -305,14 +307,20 @@ def _truncated_svd(A, k: int, oversample: int = 32, max_iters: int = 0,
     m = min(d, k + oversample) if max_iters == 0 else min(d, max_iters)
     q0 = np.asarray(jax.random.normal(jax.random.PRNGKey(seed), (d,),
                                       x.dtype), np.float64)
+    path = nm_ops.gram_path(
+        d, x.dtype, compiled=not kernels.interpret_mode(),
+        devices=len(x.sharding.device_set),
+        column_major=x.format.layout.major_to_minor == (1, 0),
+        vmem=nm_ops.vmem_capacity())
 
     def matvec(q):
         # each Lanczos iteration re-enters here: the natural QoS
         # preemption boundary for the reverse-communication driver
         base.yield_check()
         with tracing.span(tracing.LANCZOS_MATVEC):
-            return np.asarray(_gram_matvec(x, jnp.asarray(q, x.dtype)),
-                              np.float64)
+            return np.asarray(
+                _gram_matvec(x, jnp.asarray(q, x.dtype), path=path),
+                np.float64)
 
     with tracing.span(tracing.LANCZOS):
         sigma, V, iters, matvecs = _lanczos_gram(matvec, d, k, m, q0)

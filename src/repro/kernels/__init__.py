@@ -1,6 +1,7 @@
 # Pallas TPU kernels for the paper's compute hot spots:
 #   gram          — blocked A^T A (Lanczos/CG matvec substrate)
-#   normal_matvec — fused w -> X^T (X w) (the CG inner loop)
+#   normal_matvec — fused w -> X^T (X w) (the CG inner loop), and for one
+#                   vector the VPU gram_matvec (the Lanczos matvec)
 #   rf_map        — fused random-feature expansion cos(XW + b)
 #   swa           — sliding-window flash attention (recurrentgemma / qwen3-sw)
 # Each package: kernel (pl.pallas_call + BlockSpec), ops (wrapper with jnp

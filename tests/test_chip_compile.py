@@ -19,8 +19,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
     SingleDeviceSharding
 
 import repro.kernels
+from repro.common.config import V5E
 from repro.core.backends import base as backend_base
-from repro.core.backends.jax_backend import JaxBackend
+from repro.core.backends.jax_backend import JaxBackend, _gram_matvec
 from repro.kernels.gram import ops as gram_ops
 from repro.kernels.normal_matvec import ops as nm_ops
 from repro.kernels.rf_map import ops as rf_ops
@@ -140,3 +141,46 @@ def test_fused_engine_program_compiles_on_four_chip_mesh(topo):
     assert "all-reduce" in text or "reduce-scatter" in text
     [in_sharding] = jax.tree_util.tree_leaves(program.input_shardings)
     assert in_sharding.is_equivalent_to(rowblock, 2)
+
+
+def _gram_path(x, devices):
+    """The Gram matvec path for a described ``x``, as ``_truncated_svd``
+    picks it on the chip: X's layout is the one XLA gives the argument,
+    and the VMEM a v5e's."""
+    probe = jax.jit(lambda a: a).lower(x).compile()
+    layout = probe.input_formats[0][0].layout.major_to_minor
+    return nm_ops.gram_path(x.shape[1], x.dtype, compiled=True,
+                            devices=devices, column_major=layout == (1, 0),
+                            vmem=int(V5E.vmem_bytes))
+
+
+@pytest.mark.parametrize("d,path", [(8_096, "cols"), (8_192, "xla")])
+def test_gram_matvec_kernel_reads_x_in_place_on_one_chip(d, path, one_chip,
+                                                         compiled_kernels):
+    """The Lanczos Gram matvec at the ocean field's height: XLA lays
+    8,096 columns out column-major, and the program is the kernel over X
+    as it lies; 8,192 columns lie row-major, which the kernel does not
+    read, and the program is XLA's two passes. Neither copies X (6.3 GB)."""
+    x = _spec((193_536, d), one_chip)
+    assert _gram_path(x, devices=1) == path
+    with jax.default_matmul_precision("highest"):
+        compiled = _gram_matvec.lower(x, _spec((d,), one_chip),
+                                      path=path).compile()
+    assert ("tpu_custom_call" in compiled.as_text()) == (path == "cols")
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+
+
+def test_gram_matvec_on_four_chip_mesh_is_two_pass(topo):
+    """On a row-block mesh the Gram matvec stays XLA's two passes, which
+    GSPMD partitions: partial products reduced across chips, no kernel."""
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("workers",))
+    rowblock = NamedSharding(mesh, P("workers", None))
+    x = _spec((262_144, 8_096), rowblock)
+    path = _gram_path(x, devices=len(rowblock.device_set))
+    assert path == "xla"
+    with jax.default_matmul_precision("highest"):
+        compiled = _gram_matvec.lower(
+            x, _spec((8_096,), NamedSharding(mesh, P())), path=path).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    assert "all-reduce" in text

@@ -1,5 +1,6 @@
-"""Tests for the extension features: fused normal-matvec kernel, NMF
-routine, offloaded linear-head fitting."""
+"""Tests for the extension features: fused normal-matvec kernel, the
+one-pass Gram matvec kernel, NMF routine, offloaded linear-head
+fitting."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -7,9 +8,11 @@ import pytest
 
 from repro.core import AlchemistContext
 from repro.core.libraries import elemental, skylark
+from repro.kernels.normal_matvec import ops as nm_ops
 from repro.kernels.normal_matvec.normal_matvec import normal_matvec_pallas
 from repro.kernels.normal_matvec.ops import normal_matvec
-from repro.kernels.normal_matvec.ref import normal_matvec_ref
+from repro.kernels.normal_matvec.ref import gram_matvec_ref, \
+    normal_matvec_ref
 
 
 @pytest.mark.parametrize("n,d,c", [(256, 64, 4), (300, 128, 1),
@@ -34,6 +37,91 @@ def test_normal_matvec_padding_is_exact():
     want = normal_matvec_ref(x, w)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5,
                                atol=2e-4)
+
+
+#: the Gram matvec's tolerance against the float64 product, in units of
+#: its largest entry: about 16 float32 ulps, where the kernel read at most
+#: 5.6e-7 (n = 1,000, d = 8,096) and XLA's two passes as much
+GRAM_TOL = 2e-6
+
+
+@pytest.mark.parametrize("n,d", [
+    (1024, 256),          # d a multiple of 128, whole blocks of 512
+    (2048, 200),          # d not a multiple of 128
+    (512, 8096),          # the ocean field's width at a small n
+    (600, 200),           # n not a multiple of the block: masked tail
+    (100, 200),           # n below one block
+    (1000, 37),           # d under one lane tile and one 8-row strip
+    (513, 256),           # one row past a whole block
+    (4096, 130),          # eight blocks, d just over one lane tile
+    (700, 8),             # d of one 8-row strip exactly
+])
+def test_gram_matvec_matches_ref(n, d):
+    """The one-pass kernel, interpreted: within ``GRAM_TOL`` of the
+    float64 product, as the two-pass reference is."""
+    rng = np.random.default_rng(n + d)
+    x = rng.standard_normal((n, d)).astype(np.float32)
+    v = rng.standard_normal(d).astype(np.float32)
+    want = x.astype(np.float64).T @ (x.astype(np.float64) @ v)
+    got = nm_ops.gram_matvec(jnp.asarray(x), jnp.asarray(v), path="cols")
+    ref = gram_matvec_ref(jnp.asarray(x), jnp.asarray(v))
+    assert got.shape == (d,) and got.dtype == jnp.float32
+    scale = GRAM_TOL * np.abs(want).max()
+    np.testing.assert_allclose(np.asarray(got, np.float64), want, rtol=0,
+                               atol=scale)
+    np.testing.assert_allclose(np.asarray(ref, np.float64), want, rtol=0,
+                               atol=scale)
+
+
+_MIB = 2 ** 20
+
+
+@pytest.mark.parametrize(
+    "d,dtype,compiled,devices,column_major,vmem,path", [
+        (8096, jnp.float32, True, 1, True, 128 * _MIB, "cols"),  # ocean
+        (8096, jnp.float32, True, 1, False, 128 * _MIB, "xla"),  # row-major
+        (8096, jnp.float32, False, 1, True, 0, "xla"),   # CPU: interpreted
+        (8096, jnp.float32, True, 4, True, 128 * _MIB, "xla"),   # a mesh
+        (32768, jnp.float32, True, 1, True, 128 * _MIB, "xla"),  # too wide
+        (8096, jnp.float32, True, 1, True, 64 * _MIB, "xla"),    # less VMEM
+        (4096, jnp.float32, True, 1, True, 64 * _MIB, "cols"),
+        (8096, jnp.bfloat16, True, 1, True, 128 * _MIB, "xla"),
+    ])
+def test_gram_path_selection(d, dtype, compiled, devices, column_major,
+                             vmem, path):
+    assert nm_ops.gram_path(d, dtype, compiled=compiled, devices=devices,
+                            column_major=column_major, vmem=vmem) == path
+
+
+def test_vmem_capacity_is_zero_off_tpu():
+    """On the CPU Pallas describes no chip, so no block fits."""
+    assert jax.default_backend() != "tpu"
+    assert nm_ops.vmem_capacity() == 0
+
+
+@pytest.mark.parametrize("n", [2048, 2100])
+def test_truncated_svd_on_the_fused_matvec(monkeypatch, n):
+    """The Lanczos SVD with its Gram matvec forced onto the kernel
+    (interpreted here), whole blocks and a masked tail: the singular
+    values match numpy's."""
+    picked = []
+    monkeypatch.setattr(nm_ops, "gram_path",
+                        lambda *a, **k: picked.append("cols") or "cols")
+    rng = np.random.default_rng(3)
+    scales = np.linspace(40.0, 10.0, 5)
+    x = (rng.standard_normal((n, 5)) * scales) \
+        @ np.linalg.qr(rng.standard_normal((200, 5)))[0].T
+    x = (x + rng.standard_normal((n, 200))).astype(np.float32)
+    ac = AlchemistContext(num_workers=1)
+    ac.register_library("elemental", elemental)
+    try:
+        res = ac.call("elemental", "truncated_svd", A=ac.send_matrix(x), k=5)
+        s = ac.wrap(res["S"]).to_numpy().ravel()
+    finally:
+        ac.stop()
+    assert picked == ["cols"]
+    want = np.linalg.svd(x.astype(np.float64), compute_uv=False)[:5]
+    np.testing.assert_allclose(s, want, rtol=1e-5)
 
 
 def test_cg_with_fused_kernel_matches_direct():
