@@ -1,6 +1,8 @@
 """The correctness controls, at a cell's own sizes, on the chip.
 
     python3 bench/control.py --config ocean --kind svd --seeds 11 12 13
+    python3 bench/control.py --config ocean_4chip --kind svd --chips 4 \
+        --seeds 11 12 13
 
 The control is the reference put in the program's place, with its matrix
 products one precision step below what the configuration states
@@ -31,13 +33,14 @@ def _kind(name: str):
     return load_module(os.path.join(HERE, "kinds", name + ".py"))
 
 
-def svd_checks(cfg: dict, seed: int) -> dict:
-    """The control's triplets against the reference's, as a run's SVD
-    answers are compared."""
+def svd_checks(cfg: dict, seed: int, chips: int = 1) -> dict:
+    """The control's triplets against the reference's, each on the field
+    in row blocks over ``chips`` devices, as a run's SVD answers are
+    compared."""
     svd = _kind("svd")
     shape, k = (cfg["rows"], cfg["cols"]), cfg["rank"]
-    ref = svd.triplets(shape, k, seed)
-    ctl = svd.triplets(shape, k, seed, reference.HIGH)
+    ref = svd.triplets(shape, k, seed, chips)
+    ctl = svd.triplets(shape, k, seed, chips, reference.HIGH)
     return judge(svd.compare([ctl], ref), cfg["limits"])
 
 
@@ -61,6 +64,8 @@ def main(argv=None) -> int:
     ap.add_argument("--config", required=True)
     ap.add_argument("--kind", required=True, choices=sorted(CHECKS))
     ap.add_argument("--iters", type=int, default=100)
+    ap.add_argument("--chips", type=int, default=1,
+                    help="devices the svd field is row-blocked over")
     ap.add_argument("--seeds", type=int, nargs="+", required=True)
     args = ap.parse_args(argv)
     cfg = Benchmark().config({"config": args.config})
@@ -70,7 +75,7 @@ def main(argv=None) -> int:
     failed = 0
     for seed in args.seeds:
         t0 = time.perf_counter()
-        extra = (args.iters,) if args.kind == "cg_job" else ()
+        extra = (args.iters,) if args.kind == "cg_job" else (args.chips,)
         checks = CHECKS[args.kind](cfg, seed, *extra)
         failed += not within(checks)
         print(json.dumps({"seed": seed, "seconds": time.perf_counter() - t0,

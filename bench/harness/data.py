@@ -2,16 +2,26 @@
 the traffic sends. The generators follow ``chip_smoke.py``'s
 ``ocean_field`` and ``speech_data``; the ocean field is built on the
 device, one block of rows at a time, so that set-up holds one copy and
-makes no host array of its size."""
+makes no host array of its size. Over several chips each chip builds
+its own rows."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from harness.traffic import rng_for
 
 OCEAN_BLOCK = 1024
+ROWS = "rows"
+
+
+def row_blocks(chips: int) -> NamedSharding:
+    """Rows in equal blocks over the first ``chips`` devices, on a mesh of
+    the benchmark's own (the program's mesh is not the yardstick's)."""
+    mesh = Mesh(np.array(jax.devices()[:chips]), (ROWS,))
+    return NamedSharding(mesh, P(ROWS, None))
 
 
 def ocean_factors(rows: int, cols: int, k: int, seed: int):
@@ -28,13 +38,18 @@ def ocean_factors(rows: int, cols: int, k: int, seed: int):
             np.ascontiguousarray(right.T.astype(np.float32)))
 
 
-def _ocean_blocks(noise_seed, scaled_left, right_t, cols: int, block: int):
+def _ocean_blocks(noise_seed, scaled_left, right_t, cols: int, block: int,
+                  first=None):
+    """The rows of ``scaled_left``, block by block: block ``i``'s noise
+    comes from the key folded with its index in the whole field, which is
+    ``first + i`` where these rows are one chip's share of it."""
     rows = scaled_left.shape[0]
     key = jax.random.key(noise_seed)
 
     def body(i, field):
         lo = i * block
-        noise = jax.random.normal(jax.random.fold_in(key, i),
+        index = i if first is None else first + i
+        noise = jax.random.normal(jax.random.fold_in(key, index),
                                   (block, cols), jnp.float32)
         modes = jnp.matmul(
             jax.lax.dynamic_slice_in_dim(scaled_left, lo, block), right_t,
@@ -46,15 +61,46 @@ def _ocean_blocks(noise_seed, scaled_left, right_t, cols: int, block: int):
                              jnp.zeros((rows, cols), jnp.float32))
 
 
+def row_block_program(rows: int, cols: int, block: int,
+                      sharding: NamedSharding):
+    """The field's build in row blocks, each chip's rows on that chip:
+    a jitted function of (noise seed, scaled left factors, right factors
+    transposed) that runs ``_ocean_blocks`` over each chip's share of the
+    left factors, numbering its blocks from the share's first block in
+    the whole field."""
+    axis = sharding.spec[0] if sharding.spec else None
+    if not isinstance(axis, str) or any(sharding.spec[1:]):
+        raise ValueError(f"the field is built in row blocks over one mesh "
+                         f"axis, not as {sharding.spec}")
+    chips = sharding.mesh.shape[axis]
+    if rows % (chips * block):
+        raise ValueError(f"rows {rows} do not split into {chips} shares "
+                         f"of whole {block}-row blocks")
+    blocks = rows // chips // block
+
+    def share(noise_seed, left, right_t):
+        first = jax.lax.axis_index(axis) * blocks
+        return _ocean_blocks(noise_seed, left, right_t, cols, block, first)
+
+    return jax.jit(jax.shard_map(share, mesh=sharding.mesh,
+                                 in_specs=(P(), P(axis, None), P()),
+                                 out_specs=P(axis, None), check_vma=False))
+
+
 def ocean_field(rows: int, cols: int, k: int, seed: int, sharding=None):
     """The ocean field on the device: unit white noise plus k dominant
     modes, as a float32 ``jax.Array`` placed with ``sharding`` (default:
-    the default device); the same seed gives the same array."""
+    the default device); the same seed gives the same array, bit for bit,
+    on one device or in row blocks over several (``row_blocks``)."""
     block = min(OCEAN_BLOCK, rows)
     if rows % block:
         raise ValueError(f"rows {rows} is not a multiple of {block}")
     scaled_left, right_t = ocean_factors(rows, cols, k, seed)
     noise_seed = int(rng_for(seed, "ocean-noise").integers(0, 2 ** 31 - 1))
+    if sharding is not None and len(sharding.device_set) > 1:
+        make = row_block_program(rows, cols, block, sharding)
+        return make(noise_seed, jax.device_put(scaled_left, sharding),
+                    jnp.asarray(right_t))
     make = jax.jit(_ocean_blocks, static_argnames=("cols", "block"),
                    out_shardings=sharding)
     return make(noise_seed, jnp.asarray(scaled_left), jnp.asarray(right_t),

@@ -5,7 +5,10 @@ everything else is a file named after what it holds:
 * a traffic mix: ``bench/traffic/<traffic>.json``;
 * a request kind the mix sends: ``bench/kinds/<kind>.py`` (``Kind``);
 * a metric: ``bench/metrics/<name>.py`` (``read(run)``, which returns a
-  number, or None when the run holds nothing to read).
+  number, or None when the run holds nothing to read). A metric that a
+  cell of its own reads under another name, because there it moves
+  another end-to-end metric, takes the first one's reader
+  (``reader_of``).
 
 Adding a cell, configuration, mix, kind or metric adds files and entries
 and edits none.
@@ -43,6 +46,12 @@ def load_module(path: str):
     sys.modules[name] = module
     spec.loader.exec_module(module)
     return module
+
+
+def reader_of(metric: str):
+    """The ``read`` function of ``bench/metrics/<metric>.py``."""
+    return load_module(os.path.join(BENCH_DIR, "metrics",
+                                    metric + ".py")).read
 
 
 class Benchmark:

@@ -9,7 +9,8 @@ else, so it works on any trace that has those names.
 
 * busy time: the union of the intervals in which an operation ran on a
   chip, clipped to the traced window, averaged over the chips;
-* program time: summed device durations of one program's module events;
+* program time: summed device durations of one program's module events,
+  and the chips that ran it;
 * idle gaps: the spaces between busy intervals inside the window, each
   labelled by the innermost ``bench.*`` host span around its midpoint.
 """
@@ -125,6 +126,14 @@ def program_seconds(trace: Trace, name: str) -> tuple[float, int]:
     runs = [m for chip in trace.modules for m in chip
             if m.name == name and lo <= m.start < hi]
     return sum(m.end - m.start for m in runs), len(runs)
+
+
+def program_chips(trace: Trace, name: str) -> int:
+    """How many chips ran ``name`` inside the window: the chips that one
+    run of a program sharded over them spans."""
+    lo, hi = window(trace)
+    return sum(any(m.name == name and lo <= m.start < hi for m in chip)
+               for chip in trace.modules)
 
 
 def top_ops(trace: Trace, limit: int = 10) -> list[list]:

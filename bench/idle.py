@@ -43,10 +43,11 @@ from repro.core import tracing  # noqa: E402
 
 P = tracing.PREFIX
 
-#: per cell: its span metrics, each a sum over the window's calls, and
-#: the host span and device program whose lag puts the clocks side by side
-CELLS = {
-    "ocean.svd": {
+#: per traffic mix: its span metrics, each a sum over the window's calls,
+#: and the host span and device program whose lag puts the clocks side by
+#: side; every cell of the mix reads them
+MIXES = {
+    "svd": {
         "readings": {
             "svd.call_start_s": lambda t: sum(spans.time_to_next(
                 t, P + tracing.CLIENT_SUBMIT, P + tracing.LANCZOS_MATVEC)),
@@ -55,7 +56,7 @@ CELLS = {
         },
         "lag": (P + tracing.LANCZOS_MATVEC, "jit__gram_matvec"),
     },
-    "speech.cg": {
+    "cg": {
         "readings": {
             "cg.hash_s": lambda t: spans.span_seconds(
                 t, P + tracing.CLIENT_HASH),
@@ -113,7 +114,7 @@ def shares(idle: list) -> dict:
 
 def report(run, result: dict) -> dict:
     t = run.trace
-    cell = CELLS.get(run.cell["name"], {"readings": {}, "lag": None})
+    cell = MIXES.get(run.cell["traffic"], {"readings": {}, "lag": None})
     answered = sum(1 for c in run.calls if c.error is None) or 1
     out = {"workload": run.cell["name"], "seed": run.seed,
            "answered": answered,

@@ -7,8 +7,9 @@ is one ``truncated_svd(k)`` call over TCP with its own start-vector
 seed, so the engine's routine cache never answers it, and U, S and V
 are fetched to the client.
 
-The check rebuilds the field, takes the reference's top-k triplets once,
-and compares every answered call: singular values, right and left
+The check rebuilds the field in row blocks over the cell's own chips,
+on a mesh of the benchmark's, takes the reference's top-k triplets once
+there, and compares every answered call: singular values, right and left
 singular vectors, each vector up to its sign.
 """
 from __future__ import annotations
@@ -27,6 +28,7 @@ class Kind:
         self.shape = (cfg["rows"], cfg["cols"])
         self.k, self.oversample = cfg["rank"], cfg["oversample"]
         self.seed, self.spans = run.seed, run.spans
+        self.chips = run.cell["chips"]
         self.client = run.served.client(name)
         self.el = self.client.library("elemental")
         engine = run.served.engine
@@ -55,14 +57,16 @@ class Kind:
         return out
 
     def numbers(self, answers: list[dict]) -> dict:
-        return compare(answers, triplets(self.shape, self.k, self.seed))
+        return compare(answers, triplets(self.shape, self.k, self.seed,
+                                         self.chips))
 
 
-def triplets(shape, k: int, seed: int,
+def triplets(shape, k: int, seed: int, chips: int,
              precision: str = reference.HIGHEST) -> dict:
-    """The reference's top-k triplets of the field of ``seed``, rebuilt on
-    the device, with its products at ``precision``."""
-    a = data.ocean_field(*shape, k, seed)
+    """The reference's top-k triplets of the field of ``seed``, rebuilt in
+    row blocks over the first ``chips`` devices, with its products at
+    ``precision``."""
+    a = data.ocean_field(*shape, k, seed, sharding=data.row_blocks(chips))
     prepared = reference.prepare(a, precision)
     del a
     return reference.truncated_svd(

@@ -1,7 +1,8 @@
 """Gram matvecs per answered SVD inside the traced window that ran the
-one-pass kernel: device runs of ``jit__gram_matvec`` that hold the
-kernel's op (``gram_matvec_pallas``), summed over the chips. Where every
-matvec took XLA's two passes it reads 0."""
+one-pass kernel, per chip: device runs of ``jit__gram_matvec`` that hold
+the kernel's op (``gram_matvec_pallas``), summed over the chips and
+divided by the chips that ran the program. Where every matvec took XLA's
+two passes it reads 0."""
 import bisect
 
 from harness import trace
@@ -32,4 +33,5 @@ def read(run):
             if i >= 0 and op.start < runs[i].end:
                 holding.add(i)
         fused += len(holding)
-    return fused / answered
+    return fused / max(1, trace.program_chips(run.trace, PROGRAM)) \
+        / answered

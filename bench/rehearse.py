@@ -4,10 +4,13 @@ TPU v5e, without a chip, and print each one's ``memory_analysis()``.
     JAX_PLATFORMS=cpu python3 bench/rehearse.py [--only NAME ...]
 
 What it compiles: the ocean field's on-device build, the program's Gram
-matvec and U = X V at 193,536 x 8,096; the program's random-feature
-expansion, CG right-hand side, step and residual at 140,800 x 10,000;
-and the references and controls the check runs. A compile that passes is not a chip run: it
-says the compiler accepts the program and how much memory it asks for.
+matvec and U = X V at 193,536 x 8,096; the same, and the control's
+split, row-blocked over a 2x2 mesh at ``ocean_4chip``'s 774,144 rows
+(the bytes are per chip); the program's random-feature expansion, CG
+right-hand side, step and residual at 140,800 x 10,000; and the
+references and controls the check runs. A compile that passes is not a
+chip run: it says the compiler accepts the program and how much memory
+it asks for.
 """
 from __future__ import annotations
 
@@ -23,14 +26,19 @@ sys.path.insert(0, os.path.join(os.path.dirname(HERE), "src"))
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
 
 
-def programs(cfg_dir: str):
+def programs(cfg_dir: str, topo):
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
     from harness import data, reference
     from repro.core.backends import jax_backend
 
     with open(os.path.join(cfg_dir, "ocean.json")) as f:
         oc = json.load(f)
+    with open(os.path.join(cfg_dir, "ocean_4chip.json")) as f:
+        oc4 = json.load(f)
     with open(os.path.join(cfg_dir, "speech.json")) as f:
         sp = json.load(f)
     f32 = jnp.float32
@@ -69,6 +77,30 @@ def programs(cfg_dir: str):
     for precision in (reference.HIGHEST, reference.HIGH):
         yield f"ref_svd_block_{precision}", reference._gram_block, (
             prepared((n, d), precision), S((d, p), f32)), {}
+
+    mesh = Mesh(np.array(topo.devices), (data.ROWS,))
+    rows, whole = NamedSharding(mesh, P(data.ROWS, None)), \
+        NamedSharding(mesh, P())
+    n4 = oc4["rows"]
+
+    def spread(shape, dtype=f32):
+        return S(shape, dtype, sharding=rows)
+
+    def copied(shape, dtype=f32):
+        return S(shape, dtype, sharding=whole)
+
+    yield "ocean_build_4chip", data.row_block_program(
+        n4, d, data.OCEAN_BLOCK, rows), (
+        copied((), jnp.int32), spread((n4, k)), copied((k, d))), {}
+    yield "gram_matvec_4chip", jax_backend._gram_matvec, (
+        spread((n4, d)), copied((d,))), {}
+    yield "svd_u_4chip", jax.jit(lambda x, v: x @ v), (
+        spread((n4, d)), copied((d, k))), {}
+    yield "ctl_split_4chip", reference._split, (spread((n4, d)),), {}
+    for precision in (reference.HIGHEST, reference.HIGH):
+        yield f"ref_svd_block_{precision}_4chip", reference._gram_block, (
+            jax.tree.map(lambda s: spread(s.shape, s.dtype),
+                         prepared((n4, d), precision)), copied((d, p))), {}
     yield "ref_cg_residual", reference._residual, (
         S((m, feats), f32), S((m, c), f32), S((feats, width), f32),
         S((width,), f32), S((width, c), f32), S((), f32)), {"block": 1024}
@@ -92,10 +124,12 @@ def main(argv=None) -> int:
     chip = SingleDeviceSharding(topo.devices[0])
 
     def place(tree):
-        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
-            s.shape, s.dtype, sharding=chip), tree)
+        return jax.tree.map(lambda s: s if s.sharding is not None else
+                            jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                                 sharding=chip), tree)
 
-    for name, fn, shapes, static in programs(os.path.join(HERE, "configs")):
+    for name, fn, shapes, static in programs(os.path.join(HERE, "configs"),
+                                             topo):
         if args.only and name not in args.only:
             continue
         with jax.default_matmul_precision("highest"):

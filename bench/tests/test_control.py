@@ -4,7 +4,9 @@ bfloat16 three-pass) goes through the cell's own comparison and reads
 ``correct`` false, where the program's run, held to the same limits,
 reads true.
 
-The limits here are the committed ones scaled to the test's size. At the
+The four-chip cell's control runs ``control.py`` on four virtual CPU
+devices, as its run does. The limits here are the committed ones scaled
+to the test's size. At the
 cells' sizes the chip separates program and control by 88x or more, and
 the committed limits sit between them (PERF.md). At this size the two
 lie 2x to 6x apart on this seed, so each limit is set between the two
@@ -14,6 +16,8 @@ separated, so held above the program only; ``cg.residual`` 3.4e-6 and
 2.1e-5."""
 import json
 import os
+import subprocess
+import sys
 
 import pytest
 
@@ -39,8 +43,9 @@ def root(tmp_path_factory):
     tiny.edit_json(os.path.join(bench, "configs", "speech.json"),
                    rows=4096, feats=32, classes=8, rf_dim=512,
                    limits=LIMITS["speech"])
-    tiny.edit_json(os.path.join(bench, "configs", "ocean.json"),
-                   limits=LIMITS["ocean"])
+    for name in ("ocean", "ocean_4chip"):
+        tiny.edit_json(os.path.join(bench, "configs", name + ".json"),
+                       limits=LIMITS["ocean"])
     tiny.edit_json(os.path.join(bench, "traffic", "cg.json"),
                    requests=[{"kind": "cg_job", "share": 1.0,
                               "max_iters": ITERS, "tol": 0.0}])
@@ -65,11 +70,33 @@ def program_is_correct(root, cell):
     return res["checks"]
 
 
+def control_on_chips(root, cell, config, chips):
+    """``control.py`` on ``chips`` devices, in a process of its own, with
+    the program imported from this checkout."""
+    env = tiny.cpu_env(root, cell)
+    env["PYTHONPATH"] = os.pathsep.join(
+        filter(None, [os.path.join(tiny.ROOT, "src"),
+                      env.get("PYTHONPATH", "")]))
+    p = subprocess.run(
+        [sys.executable, os.path.join(root, "bench", "control.py"),
+         "--config", config, "--kind", "svd", "--chips", str(chips),
+         "--seeds", str(SEED)],
+        capture_output=True, text=True, env=env, timeout=600)
+    assert p.returncode == 0, p.stderr
+    line = json.loads(p.stdout.strip().splitlines()[-1])
+    assert line["correct"] is False, p.stdout
+    return line["checks"]
+
+
 @pytest.mark.parametrize("cell,config,kind", [
-    ("ocean.svd", "ocean", "svd"), ("speech.cg", "speech", "cg_job")])
+    ("ocean.svd", "ocean", "svd"), ("speech.cg", "speech", "cg_job"),
+    ("ocean.svd_4chip", "ocean_4chip", "svd")])
 def test_control_is_not_correct(root, control, cell, config, kind):
     extra = (ITERS,) if kind == "cg_job" else ()
-    checks = control.CHECKS[kind](cfg(root, config), SEED, *extra)
+    if cell == "ocean.svd_4chip":
+        checks = control_on_chips(root, cell, config, 4)
+    else:
+        checks = control.CHECKS[kind](cfg(root, config), SEED, *extra)
     assert not within(checks), checks
     prog = program_is_correct(root, cell)
     assert set(prog) == set(checks)

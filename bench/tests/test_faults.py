@@ -3,7 +3,10 @@ underneath, reads ``correct`` false; unbroken, it reads true. The fault
 every cell can have is an answer altered where it is produced: one
 singular value of each SVD, or the solution of each CG job, off by a
 thousandth. A CG job can also be answered with another job's solution,
-as a cache keyed too loosely would answer it."""
+as a cache keyed too loosely would answer it. A cell over several chips
+can leave out the exchange between them: each chip's Gram matvec with
+its own rows and the partial products never summed. The four-chip cell
+runs on four virtual CPU devices."""
 import pytest
 
 import tiny
@@ -40,8 +43,24 @@ def stale(*args, _fn=impl.fn, **kwargs):
 JaxBackend._registered[key] = dataclasses.replace(impl, fn=stale)
 """
 
+#: the Gram matvec of the SVD as each chip computes it over its own rows,
+#: X_i^T (X_i v), with the sum over the chips left out: what reaches the
+#: Lanczos loop is the first chip's partial product
+NO_EXCHANGE = """
+import jax
+from jax.sharding import PartitionSpec as P
+from repro.core.backends import jax_backend
+def local_only(x, v, path="xla"):
+    rows = x.sharding.spec[0]
+    return jax.shard_map(lambda xb, vb: xb.T @ (xb @ vb),
+                         mesh=x.sharding.mesh, in_specs=(P(rows, None), P()),
+                         out_specs=P(), check_vma=False)(x, v)
+jax_backend._gram_matvec = local_only
+"""
+
 CELLS = {
     "ocean.svd": ("elemental", "truncated_svd", "S"),
+    "ocean.svd_4chip": ("elemental", "truncated_svd", "S"),
     "speech.cg": ("skylark", "cg_solve", "W"),
 }
 
@@ -69,6 +88,10 @@ def test_altered_answer_is_not_correct(root, cell):
 
 def test_another_jobs_solution_is_not_correct(root):
     assert_not_correct(*tiny.run(root, "speech.cg", fault=STALE))
+
+
+def test_exchange_between_chips_left_out_is_not_correct(root):
+    assert_not_correct(*tiny.run(root, "ocean.svd_4chip", fault=NO_EXCHANGE))
 
 
 def assert_not_correct(rc, out, err):

@@ -49,3 +49,22 @@ def test_two_pass_program_reads_zero():
 def test_reads_nothing_without_a_trace_or_an_answer():
     assert READ(SimpleNamespace(trace=None, calls=[])) is None
     assert READ(_run([], [], answered=0)) is None
+
+
+def test_reads_per_chip():
+    """Four chips each run the SVD's two matvecs with the kernel: 2 per
+    SVD per chip, not 8; one of the four without it reads 1.5."""
+    modules = [Interval("jit__gram_matvec", 1, 2),
+               Interval("jit__gram_matvec", 3, 4)]
+    fused = [Interval("gram_matvec_pallas.1", 1.1, 1.9),
+             Interval("gram_matvec_pallas.1", 3.1, 3.9)]
+    plain = [Interval("all-reduce", 1.9, 2.0)]
+
+    def run(ops):
+        return SimpleNamespace(
+            trace=Trace(modules=[modules] * 4, ops=ops,
+                        spans=[Interval("bench.window", 0, 10)]),
+            calls=[SimpleNamespace(request={"kind": "svd"}, error=None)])
+
+    assert READ(run([fused] * 4)) == 2.0
+    assert READ(run([fused] * 3 + [plain])) == 1.5

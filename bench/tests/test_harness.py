@@ -72,9 +72,28 @@ def test_new_config_mix_and_metric_are_found_by_name(tmp_path):
     assert {k: after[k] for k in before} == before
 
 
-def run_bench(root, env_extra, prelude=""):
+def test_four_chip_cell_reports_its_own_metrics(tmp_path):
+    """On four virtual devices the cell reports its own end-to-end metric
+    and the per-layer metrics that move it; the device's readings need a
+    TPU's trace, which the CPU does not write."""
+    root = tiny.copy(str(tmp_path))
+    rc, out, err = tiny.run(root, "ocean.svd_4chip", trace=0)
+    assert rc == 0, err
+    res = tiny.result(out)
+    assert res["correct"] and res["device"]["count"] == 4, err
+    assert set(res["metrics"]) == {"svd_s.4chip", "setup_s"}
+    rc, out, err = tiny.run(root, "ocean.svd_4chip", trace=1)
+    res = tiny.result(out)
+    assert res["correct"], err
+    assert set(res["metrics"]) == {
+        "svd.device_idle.4chip", "svd.matvecs.4chip", "svd.compiles.4chip",
+        "svd.fused_matvecs.4chip", "svd.fetch_s.4chip"}
+    assert res["metrics"]["svd.matvecs.4chip"]["value"] == 24  # 8 + 16
+
+
+def run_bench(root, env_extra, prelude="", workload="ocean.svd"):
     code = (prelude + "import runpy, sys\n"
-            f"sys.argv = ['run.py', '--workload', 'ocean.svd', '--seed', "
+            f"sys.argv = ['run.py', '--workload', {workload!r}, '--seed', "
             f"'1', '--seconds', '1', '--trace', '0']\n"
             f"runpy.run_path({os.path.join(root, 'bench', 'run.py')!r}, "
             "run_name='__main__')\n")
@@ -121,6 +140,20 @@ def test_too_few_chips_fails(tmp_path):
     assert p.returncode == 2
     assert p.stdout == ""
     assert "needs 4 chips" in p.stderr
+
+
+def test_four_chip_cell_on_one_chip_fails(tmp_path):
+    root = tiny.copy(str(tmp_path))
+    fake = ("import jax\n"
+            "class Chip:\n"
+            "    platform = 'tpu'\n"
+            "    device_kind = 'TPU v5 lite'\n"
+            "jax.devices = lambda *a, **k: [Chip()]\n")
+    p = run_bench(root, {"JAX_PLATFORMS": "cpu"}, prelude=fake,
+                  workload="ocean.svd_4chip")
+    assert p.returncode == 2
+    assert p.stdout == ""
+    assert "needs 4 chips, JAX found 1" in p.stderr
 
 
 def test_without_the_program_no_result(tmp_path):

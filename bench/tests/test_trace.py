@@ -115,3 +115,32 @@ def test_synthetic_gaps_take_the_innermost_span_at_their_midpoint():
     assert gaps == [["bench.fetch", pytest.approx(3.0)],
                     ["bench.send", pytest.approx(2.0)],
                     ["host", pytest.approx(1.0)]]
+
+
+def _roofline_run(planes: int, rows: int):
+    """``planes`` chips over a 10 s window, each running the Gram matvec
+    three times for 1 ms; ``rows`` in the whole field, 1,024 columns."""
+    runs = [Interval("jit__gram_matvec", t, t + 1e-3) for t in (1, 2, 3)]
+
+    class Run:
+        trace = Trace(modules=[list(runs) for _ in range(planes)],
+                      ops=[[] for _ in range(planes)],
+                      spans=[Interval("bench.window", 0, 10)])
+        config = {"rows": rows, "cols": 1024}
+        peaks = {"hbm_bytes_per_s": 819e9, "flops_per_s": 197e12}
+    return Run
+
+
+def test_roofline_share_is_per_chip():
+    """Over four chips each run reads a quarter of the rows: 4 x 8,192
+    rows read at 1 ms a run on each chip is the one-chip share of 8,192
+    rows at 1 ms, not four times it. On one plane the reader is the
+    whole-field formula, to the last bit."""
+    read = load_module(os.path.join(METRICS, "svd.matvec_roofline.py")).read
+    one, four = read(_roofline_run(1, 8192)), read(_roofline_run(4, 32768))
+    least = 8192 * 1024 * 4 / 819e9
+    assert one == 100.0 * 3 * least / trace.program_seconds(
+        _roofline_run(1, 8192).trace, "jit__gram_matvec")[0]
+    assert four == pytest.approx(one, rel=1e-12)
+    assert trace.program_chips(_roofline_run(4, 32768).trace,
+                               "jit__gram_matvec") == 4
