@@ -44,6 +44,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro import kernels
 from repro.analysis import locktrace
@@ -413,15 +414,21 @@ def _cg_rhs(x, y):
 _RESIDUAL_BLOCK = 1024
 
 
-@jax.jit
-def _cg_residual(x, y, lam_n, b_norm, w):
-    """max over columns of ||X^T Y - (X^T X + lam_n I) w|| / ||b||,
-    evaluated on w itself rather than carried by the CG recurrence, as
-    X^T (Y - X w) - lam_n w summed over blocks of rows. In one float32
-    product over all rows the rounding is of the size of the residual it
-    measures: at 131,072 x 10,000 on a v5e it read 1.34e-5 where float64
-    read 1.88e-5; by blocks of 1,024 rows it read 1.88e-5."""
-    n, d = x.shape
+def _row_split(a):
+    """``(mesh, axis)`` where ``a``'s rows lie in equal blocks over a mesh
+    axis, one block a device (the engine's row-block layout); None where
+    one device holds all of them, or each device a full copy."""
+    sharding = getattr(a, "sharding", None)
+    spec = getattr(sharding, "spec", None)
+    if not spec or not isinstance(spec[0], str) or any(spec[1:]) or \
+            len(sharding.device_set) == 1:
+        return None
+    return sharding.mesh, spec[0]
+
+
+def _residual_sum(x, y, w):
+    """X^T (Y - X w), summed over blocks of rows."""
+    n = x.shape[0]
     blk = min(_RESIDUAL_BLOCK, n)
     full = n // blk
 
@@ -435,6 +442,31 @@ def _cg_residual(x, y, lam_n, b_norm, w):
     acc = jax.lax.fori_loop(0, full, body, jnp.zeros_like(w))
     if full * blk < n:
         acc = acc + part(x[full * blk:], y[full * blk:])
+    return acc
+
+
+@functools.partial(jax.jit, static_argnames="rows")
+def _cg_residual(x, y, lam_n, b_norm, w, rows=None):
+    """max over columns of ||X^T Y - (X^T X + lam_n I) w|| / ||b||,
+    evaluated on w itself rather than carried by the CG recurrence, as
+    X^T (Y - X w) - lam_n w summed over blocks of rows. In one float32
+    product over all rows the rounding is of the size of the residual it
+    measures: at 131,072 x 10,000 on a v5e it read 1.34e-5 where float64
+    read 1.88e-5; by blocks of 1,024 rows it read 1.88e-5.
+
+    With ``rows`` (:func:`_row_split` of X) each device sums the blocks
+    of its own rows and one ``psum`` adds the devices' (D, c) partials:
+    sliced on a split row axis, the blocks would gather all of X onto
+    every device (at 94,208 x 60,000 over four v5e chips, 22.6 GB on
+    each)."""
+    if rows is None:
+        acc = _residual_sum(x, y, w)
+    else:
+        mesh, axis = rows
+        acc = jax.shard_map(
+            lambda xb, yb, wb: jax.lax.psum(_residual_sum(xb, yb, wb), axis),
+            mesh=mesh, in_specs=(P(axis, None), P(axis, None), P()),
+            out_specs=P(), check_vma=False)(x, y, w)
     return jnp.max(jnp.linalg.norm(acc - lam_n * w, axis=0)
                    / jnp.maximum(b_norm, 1e-30))
 
@@ -443,17 +475,20 @@ def _cg_residual(x, y, lam_n, b_norm, w):
 def _cg_solve(X, Y, lam: float = 1e-5, rf_dim: int = 0,
               bandwidth: float = 1.0, max_iters: int = 200,
               tol: float = 1e-8, seed: int = 0, use_pallas: bool = False):
-    with tracing.span(tracing.CG):
+    # the expansion is row-local, so Z's rows lie where X's do
+    rows = _row_split(X)
+    shards = 1 if rows is None else rows[0].shape[rows[1]]
+    with tracing.span(tracing.CG, shards=shards):
         x = X
         if rf_dim:
-            with tracing.span(tracing.CG_RF_MAP):
+            with tracing.span(tracing.CG_RF_MAP, shards=shards):
                 x = _rf_expand(x, rf_dim, bandwidth=bandwidth, seed=seed,
                                use_pallas=use_pallas)
         y = Y
         n, d = x.shape
         lam_n = jnp.asarray(n * lam, x.dtype)
 
-        with tracing.span(tracing.CG_RHS):
+        with tracing.span(tracing.CG_RHS, shards=shards):
             b = _cg_rhs(x, y)                        # (d, c) rhs
             b_norm = jnp.linalg.norm(b, axis=0)
             w = jnp.zeros(b.shape, x.dtype)
@@ -470,7 +505,7 @@ def _cg_solve(X, Y, lam: float = 1e-5, rf_dim: int = 0,
         state = (w, r, p, rs)
         while iters < max_iters and rel > tol:
             base.yield_check()          # QoS iteration boundary
-            with tracing.span(tracing.CG_STEP):
+            with tracing.span(tracing.CG_STEP, shards=shards):
                 state = _step(x, lam_n, state)
                 iters += 1
                 rel = float(jnp.max(jnp.sqrt(state[3])
@@ -480,9 +515,9 @@ def _cg_solve(X, Y, lam: float = 1e-5, rf_dim: int = 0,
         # v5e it fell to 7e-8 while b - A w stalled at 2e-5. The loop
         # stops on the recurrence; what is reported is the true residual
         # of the W returned, one more pass over the rows.
-        with tracing.span(tracing.CG_RESIDUAL):
+        with tracing.span(tracing.CG_RESIDUAL, shards=shards):
             true_rel = float(_cg_residual(x, y.astype(x.dtype), lam_n,
-                                          b_norm, state[0]))
+                                          b_norm, state[0], rows=rows))
 
         return {
             "W": state[0],
@@ -497,6 +532,10 @@ def _cg_solve(X, Y, lam: float = 1e-5, rf_dim: int = 0,
                 else "none",
                 "normal_matvec": "pallas"
                 if nm_ops.uses_kernel(d, use_pallas) else "jnp"},
+            # the devices Z's rows are split over, and the bytes of the
+            # (D, c) partial product that each step sums across them
+            "shards": shards,
+            "reduce_bytes": b.size * b.dtype.itemsize if shards > 1 else 0,
         }
 
 

@@ -489,8 +489,9 @@ class _Connection:
         sizes: list[int] = []
         total = 0
         wire_total = 0
+        read = transfer.row_reader(arr)
         for idx, (lo, hi) in enumerate(plan):
-            block = np.asarray(arr[lo:hi])
+            block = read(lo, hi)
             body = msgpack.packb({"lo": lo, "hi": hi,
                                   "array": wire.pack_ndarray(block)})
             frame_len = wire.HEADER_BYTES + len(body)

@@ -1,6 +1,8 @@
 """Compile rehearsals for a TPU v5e that is described, not attached: the
-served kernels at the paper's widths with ``interpret=False``, and one
-fused engine program over a four-device mesh with row-block operands.
+served kernels at the paper's widths with ``interpret=False``, one
+fused engine program over a four-device mesh with row-block operands,
+and the CG solve's programs at the 60,000-feature speech deployment's
+size in row blocks over a 2x2 mesh.
 
 The topology is described inside a module fixture (never at import), so
 every test worker collects the same tests and only the worker given this
@@ -9,6 +11,7 @@ compiles: an entry written for a described chip cannot be read back
 without one.
 """
 import os
+import re
 
 import numpy as np
 import pytest
@@ -21,6 +24,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
 import repro.kernels
 from repro.common.config import V5E
 from repro.core.backends import base as backend_base
+from repro.core.backends import jax_backend
 from repro.core.backends.jax_backend import JaxBackend, _gram_matvec
 from repro.kernels.gram import ops as gram_ops
 from repro.kernels.normal_matvec import ops as nm_ops
@@ -184,3 +188,112 @@ def test_gram_matvec_on_four_chip_mesh_is_two_pass(topo):
     text = compiled.as_text()
     assert "tpu_custom_call" not in text
     assert "all-reduce" in text
+
+
+#: the 60,000-feature speech deployment on four chips: 94,208 rows of
+#: 440 features, 147 classes, Z = 94,208 x 60,000 float32 (22.6 GB)
+CG_ROWS, CG_FEATS, CG_CLASSES, CG_WIDTH = 94_208, 440, 147, 60_000
+COLLECTIVE = re.compile(r"= (\S+) (all-reduce|all-gather|reduce-scatter|"
+                        r"all-to-all|collective-permute)(?:-start)?\(")
+
+
+def _cg_programs(mesh):
+    """The programs of ``_cg_solve`` as it runs them on a row-block X,
+    with their operands: the expansion, the right-hand side, the step
+    (a jit of a lambda, as the solve builds it) and the true residual."""
+    rows = NamedSharding(mesh, P("workers", None))
+    whole = NamedSharding(mesh, P())
+    n, f, c, d = CG_ROWS, CG_FEATS, CG_CLASSES, CG_WIDTH
+    state = (_spec((d, c), whole),) * 3 + (_spec((c,), whole),)
+    step = jax.jit(lambda x, lam_n, st: jax_backend._cg_step(x, lam_n, st))
+    return {
+        "rf_expand": (jax_backend._rf_expand, (_spec((n, f), rows),),
+                      {"rf_dim": d, "bandwidth": 20.0, "seed": 0,
+                       "use_pallas": False}),
+        "cg_rhs": (jax_backend._cg_rhs,
+                   (_spec((n, d), rows), _spec((n, c), rows)), {}),
+        "cg_step": (step, (_spec((n, d), rows), _spec((), whole), state), {}),
+        "cg_residual": (jax_backend._cg_residual, (
+            _spec((n, d), rows), _spec((n, c), rows), _spec((), whole),
+            _spec((c,), whole), _spec((d, c), whole)),
+            {"rows": (mesh, "workers")}),
+    }
+
+
+@pytest.mark.parametrize("name", ["rf_expand", "cg_rhs", "cg_step",
+                                  "cg_residual"])
+def test_cg_program_fits_a_chip_in_row_blocks_over_four(name, topo):
+    """Each program of the solve compiles for a 2x2 v5e within one chip's
+    16 GB, and none gathers Z (5.65 GB a chip, 22.6 GB in all): the
+    expansion runs on each chip's rows; the right-hand side and the step
+    sum one (60,000, 147) partial product across the chips; the residual
+    runs each chip's own rows in blocks and sums its partials the same
+    way."""
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("workers",))
+    fn, args, static = _cg_programs(mesh)[name]
+    with jax.default_matmul_precision("highest"):
+        lowered = fn.lower(*args, **static)
+    # every product at full float32 precision, inside the shard_map too
+    dots = [ln for ln in lowered.as_text().splitlines()
+            if "dot_general" in ln]
+    assert dots and all("HIGHEST" in ln for ln in dots), name
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    total = mem.argument_size_in_bytes + mem.output_size_in_bytes + \
+        mem.temp_size_in_bytes
+    assert total < 15 * 2 ** 30, (name, total)
+    collectives = [(op, shape.split("{")[0]) for shape, op in
+                   COLLECTIVE.findall(compiled.as_text())]
+    if name == "rf_expand":
+        assert collectives == []
+    else:
+        assert collectives == [("all-reduce", "f32[60000,147]")], collectives
+
+
+#: the tables of source files, functions and frames in a program's text
+DEBUG_TABLE = re.compile(r"^(FileNames|FunctionNames|FileLocations|"
+                         r"StackFrames|\d+ [{\"].*)$")
+
+
+def _program_text(compiled) -> str:
+    """A compiled program's HLO without its source locations."""
+    return "\n".join(re.sub(r", metadata=\{[^}]*\}", "", line)
+                     for line in compiled.as_text().splitlines()
+                     if not DEBUG_TABLE.match(line))
+
+
+def test_one_chip_cg_residual_is_unchanged(one_chip):
+    """On one chip (``speech``: 140,800 x 10,000) the residual compiles to
+    the program it compiled to before the row-block path existed."""
+    def _cg_residual(x, y, lam_n, b_norm, w):
+        # the residual before the row-block path, word for word, under
+        # its name (the program's)
+        n, d = x.shape
+        blk = min(jax_backend._RESIDUAL_BLOCK, n)
+        full = n // blk
+
+        def part(xb, yb):
+            return xb.T @ (yb - xb @ w)
+
+        def body(i, acc):
+            return acc + part(
+                jax.lax.dynamic_slice_in_dim(x, i * blk, blk),
+                jax.lax.dynamic_slice_in_dim(y, i * blk, blk))
+
+        acc = jax.lax.fori_loop(0, full, body, jnp.zeros_like(w))
+        if full * blk < n:
+            acc = acc + part(x[full * blk:], y[full * blk:])
+        return jnp.max(jnp.linalg.norm(acc - lam_n * w, axis=0)
+                       / jnp.maximum(b_norm, 1e-30))
+
+    parents = jax.jit(_cg_residual)
+    args = (_spec((140_800, 10_000), one_chip),
+            _spec((140_800, 147), one_chip), _spec((), one_chip),
+            _spec((147,), one_chip), _spec((10_000, 147), one_chip))
+    with jax.default_matmul_precision("highest"):
+        now = jax_backend._cg_residual.lower(*args).compile()
+        before = parents.lower(*args).compile()
+    text = _program_text(before)
+    assert text.startswith("HloModule jit__cg_residual,")
+    assert "ENTRY" in text and "while" in text and "stack_frame" not in text
+    assert _program_text(now) == text

@@ -58,7 +58,8 @@ SERVED = [
 @pytest.fixture(scope="module")
 def served_trace(tmp_path_factory):
     """Spans of one served SVD and one CG job, by name: ``(thread line,
-    start_ns, end_ns)``; and what the routines reported."""
+    start_ns, end_ns)``; the ids each span carried, by name; and what the
+    routines reported."""
     from jax.profiler import ProfileData
 
     rng = np.random.default_rng(0)
@@ -92,6 +93,7 @@ def served_trace(tmp_path_factory):
     path = glob.glob(os.path.join(str(out), "**", "*.xplane.pb"),
                      recursive=True)[0]
     spans: dict = {}
+    ids: dict = {}
     for plane in ProfileData.from_file(path).planes:
         if plane.name != "/host:CPU":
             continue
@@ -100,18 +102,20 @@ def served_trace(tmp_path_factory):
                 if ev.name.startswith(P):
                     spans.setdefault(ev.name[len(P):], []).append(
                         (i, ev.start_ns, ev.start_ns + ev.duration_ns))
-    return spans, matvecs, iterations
+                    ids.setdefault(ev.name[len(P):], []).append(
+                        dict(ev.stats))
+    return spans, matvecs, iterations, ids
 
 
 @pytest.mark.parametrize("name", SERVED)
 def test_served_path_writes_span(served_trace, name):
-    spans, _, _ = served_trace
+    spans, _, _, _ = served_trace
     assert spans.get(name), f"no {P}{name} in the trace"
 
 
 @pytest.mark.parametrize("child", sorted(PARENTS))
 def test_span_nests_inside_its_layer(served_trace, child):
-    spans, _, _ = served_trace
+    spans, _, _, _ = served_trace
     parents = spans[PARENTS[child]]
     for line, s, e in spans[child]:
         assert any(pl == line and ps <= s and e <= pe
@@ -120,12 +124,22 @@ def test_span_nests_inside_its_layer(served_trace, child):
 
 
 def test_one_span_per_host_loop_iteration(served_trace):
-    spans, matvecs, iterations = served_trace
+    spans, matvecs, iterations, _ = served_trace
     assert len(spans[tracing.LANCZOS_MATVEC]) == matvecs
     assert len(spans[tracing.CG_STEP]) == iterations == 5
     # X crosses in 4 chunks, the first matrix also in 4, Y in 1
     assert len(spans[tracing.server_frame("UPLOAD_CHUNK")]) == 9
     assert len(spans[tracing.CLIENT_UPLOAD]) == 3
+
+
+@pytest.mark.parametrize("name", [
+    tracing.CG, tracing.CG_RF_MAP, tracing.CG_RHS, tracing.CG_STEP,
+    tracing.CG_RESIDUAL])
+def test_cg_spans_carry_the_chips_z_spans(served_trace, name):
+    """On one device Z's rows are split over one chip: every CG span
+    says ``shards`` 1 (four virtual devices: ``test_cg_mesh4.py``)."""
+    _, _, _, ids = served_trace
+    assert ids[name] and all(i.get("shards") == 1 for i in ids[name])
 
 
 def test_every_request_frame_has_a_server_span():
