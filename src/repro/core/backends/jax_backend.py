@@ -288,11 +288,27 @@ def _qr(A):
     return {"Q": q, "R": r}
 
 
-@functools.partial(jax.jit, static_argnames="path")
-def _gram_matvec(x, v, path: str = "xla"):
+@functools.partial(jax.jit, static_argnames=("path", "rows"))
+def _gram_matvec(x, v, path: str = "xla", rows=None):
     """v -> X^T (X v); never materializes X^T X. ``path`` is
-    ``nm_ops.gram_path``'s: the one-pass kernel, or XLA's two passes."""
-    return nm_ops.gram_matvec(x, v, path=path)
+    ``nm_ops.gram_path``'s: the one-pass kernel, or XLA's two passes.
+
+    With ``rows`` (:func:`_row_split` of X) ``path`` runs on each
+    device's own block of rows under a ``shard_map`` (GSPMD cannot
+    partition the kernel, and would gather X onto every device), and one
+    all-reduce adds the devices' (d,) partials. The sum is left to GSPMD,
+    outside the ``shard_map``: it compiles to the same one collective as
+    a ``psum`` inside would, but under the name ``all-reduce`` that the
+    device trace's collective readers look for (a ``psum`` is named
+    ``psum``)."""
+    if rows is None:
+        return nm_ops.gram_matvec(x, v, path=path)
+    mesh, axis = rows
+    parts = jax.shard_map(
+        lambda xb, vb: nm_ops.gram_matvec(xb, vb, path=path)[None],
+        mesh=mesh, in_specs=(P(axis, None), P()), out_specs=P(axis, None),
+        check_vma=False)(x, v)
+    return parts.sum(axis=0)
 
 
 @register("elemental", "truncated_svd", accepts=_DENSE)
@@ -308,11 +324,16 @@ def _truncated_svd(A, k: int, oversample: int = 32, max_iters: int = 0,
     m = min(d, k + oversample) if max_iters == 0 else min(d, max_iters)
     q0 = np.asarray(jax.random.normal(jax.random.PRNGKey(seed), (d,),
                                       x.dtype), np.float64)
+    rows = _row_split(x)
+    shards = 1 if rows is None else rows[0].shape[rows[1]]
     path = nm_ops.gram_path(
         d, x.dtype, compiled=not kernels.interpret_mode(),
-        devices=len(x.sharding.device_set),
+        devices=len(x.sharding.device_set), row_blocks=rows is not None,
         column_major=x.format.layout.major_to_minor == (1, 0),
         vmem=nm_ops.vmem_capacity())
+    # the split reaches the program only where it changes it: the kernel
+    # on row blocks; every other path is called as on one device
+    split = {"rows": rows} if path == "cols" and rows is not None else {}
 
     def matvec(q):
         # each Lanczos iteration re-enters here: the natural QoS
@@ -320,10 +341,11 @@ def _truncated_svd(A, k: int, oversample: int = 32, max_iters: int = 0,
         base.yield_check()
         with tracing.span(tracing.LANCZOS_MATVEC):
             return np.asarray(
-                _gram_matvec(x, jnp.asarray(q, x.dtype), path=path),
+                _gram_matvec(x, jnp.asarray(q, x.dtype), path=path,
+                             **split),
                 np.float64)
 
-    with tracing.span(tracing.LANCZOS):
+    with tracing.span(tracing.LANCZOS, path=path, shards=shards):
         sigma, V, iters, matvecs = _lanczos_gram(matvec, d, k, m, q0)
     v_dev = jnp.asarray(V, x.dtype)
     U = (x @ v_dev) / jnp.maximum(jnp.asarray(sigma, x.dtype), 1e-30)

@@ -10,8 +10,8 @@ This module is the one place their names are spelled: the server, the
 client, the scheduler and the backends take them from here, and so do
 the trace readers and tests (``PREFIX + name`` is the name in a trace).
 Spans wrap host code only, never the body of a jitted function or a
-Pallas kernel (``repro.analysis`` rule TRC001). Ids are small ints:
-``task`` and ``session``.
+Pallas kernel (``repro.analysis`` rule TRC001). Ids are small ints
+(``task``, ``session``, ``shards``) or short strings (``path``).
 """
 from __future__ import annotations
 
@@ -50,7 +50,8 @@ SERVER_DEVICE_PUT = "server.device_put"
 # ---- scheduler worker and backends ----------------------------------------
 #: one task body on a scheduler worker (ids ``task``, ``session``)
 TASK = "task"
-#: the Lanczos host loop of the jax backend's ``truncated_svd``
+#: the Lanczos host loop of the jax backend's ``truncated_svd`` (ids
+#: ``path``, the Gram matvec's, and ``shards``, the devices X's rows span)
 LANCZOS = "lanczos"
 #: one Gram matvec: the vector to the device, the program, the vector back
 LANCZOS_MATVEC = "lanczos.matvec"

@@ -56,16 +56,19 @@ def vmem_capacity() -> int:
 
 
 def gram_path(d: int, dtype, *, compiled: bool, devices: int,
-              column_major: bool, vmem: int) -> str:
+              row_blocks: bool, column_major: bool, vmem: int) -> str:
     """How :func:`gram_matvec` applies X^T X to one vector for an (n, d)
     operand: ``"cols"``, the one-pass kernel over the rows of X^T, or
     ``"xla"``, two passes of XLA. The kernel needs a platform that
-    compiles it (on the CPU it would run interpreted), X on one device
-    (GSPMD cannot partition a kernel, so it would gather X), float32 (its
-    tiles), X column-major (the layout it reads without a copy) and a
-    block that fits three quarters of the chip's ``vmem`` bytes."""
-    if not compiled or devices != 1 or jnp.dtype(dtype) != jnp.float32 \
-            or not column_major \
+    compiles it (on the CPU it would run interpreted), X on one device or
+    its rows in equal blocks over one mesh axis (``row_blocks``: each
+    device then runs the kernel on its own block; GSPMD cannot partition
+    a kernel, so on any other split it would gather X), float32 (its
+    tiles), each device's block column-major (the layout it reads without
+    a copy) and a block that fits three quarters of the chip's ``vmem``
+    bytes."""
+    if not compiled or (devices != 1 and not row_blocks) \
+            or jnp.dtype(dtype) != jnp.float32 or not column_major \
             or 4 * vmem_bytes(d, _GRAM_BLOCK) > 3 * vmem:
         return "xla"
     return "cols"

@@ -10,6 +10,7 @@ file loads the TPU compiler. JAX's persistent cache is off around these
 compiles: an entry written for a described chip cannot be read back
 without one.
 """
+import functools
 import os
 import re
 
@@ -147,15 +148,22 @@ def test_fused_engine_program_compiles_on_four_chip_mesh(topo):
     assert in_sharding.is_equivalent_to(rowblock, 2)
 
 
-def _gram_path(x, devices):
+#: a collective in a compiled program's text: its shape, then its op
+COLLECTIVE = re.compile(r"= (\S+) (all-reduce|all-gather|reduce-scatter|"
+                        r"all-to-all|collective-permute)(?:-start)?\(")
+
+
+def _gram_path(x):
     """The Gram matvec path for a described ``x``, as ``_truncated_svd``
-    picks it on the chip: X's layout is the one XLA gives the argument,
-    and the VMEM a v5e's."""
+    picks it on the chip: X's split is its sharding's, each device's
+    layout the one XLA gives the argument, and the VMEM a v5e's."""
     probe = jax.jit(lambda a: a).lower(x).compile()
     layout = probe.input_formats[0][0].layout.major_to_minor
-    return nm_ops.gram_path(x.shape[1], x.dtype, compiled=True,
-                            devices=devices, column_major=layout == (1, 0),
-                            vmem=int(V5E.vmem_bytes))
+    return nm_ops.gram_path(
+        x.shape[1], x.dtype, compiled=True,
+        devices=len(x.sharding.device_set),
+        row_blocks=jax_backend._row_split(x) is not None,
+        column_major=layout == (1, 0), vmem=int(V5E.vmem_bytes))
 
 
 @pytest.mark.parametrize("d,path", [(8_096, "cols"), (8_192, "xla")])
@@ -166,7 +174,7 @@ def test_gram_matvec_kernel_reads_x_in_place_on_one_chip(d, path, one_chip,
     as it lies; 8,192 columns lie row-major, which the kernel does not
     read, and the program is XLA's two passes. Neither copies X (6.3 GB)."""
     x = _spec((193_536, d), one_chip)
-    assert _gram_path(x, devices=1) == path
+    assert _gram_path(x) == path
     with jax.default_matmul_precision("highest"):
         compiled = _gram_matvec.lower(x, _spec((d,), one_chip),
                                       path=path).compile()
@@ -174,27 +182,46 @@ def test_gram_matvec_kernel_reads_x_in_place_on_one_chip(d, path, one_chip,
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
 
 
-def test_gram_matvec_on_four_chip_mesh_is_two_pass(topo):
-    """On a row-block mesh the Gram matvec stays XLA's two passes, which
-    GSPMD partitions: partial products reduced across chips, no kernel."""
+def test_gram_matvec_on_four_chip_mesh_runs_the_kernel_per_chip(
+        topo, compiled_kernels):
+    """On a row-block mesh (a 2x2 v5e, 65,536 rows of 8,096 a chip) each
+    chip's block lies column-major, and the Gram matvec is the one-pass
+    kernel on each chip's rows under a ``shard_map``: one kernel, one
+    all-reduce of the (d,) partials, and no copy of X."""
     mesh = Mesh(np.array(topo.devices).reshape(4), ("workers",))
     rowblock = NamedSharding(mesh, P("workers", None))
     x = _spec((262_144, 8_096), rowblock)
-    path = _gram_path(x, devices=len(rowblock.device_set))
-    assert path == "xla"
+    path = _gram_path(x)
+    assert path == "cols"
     with jax.default_matmul_precision("highest"):
         compiled = _gram_matvec.lower(
-            x, _spec((8_096,), NamedSharding(mesh, P())), path=path).compile()
+            x, _spec((8_096,), NamedSharding(mesh, P())), path=path,
+            rows=jax_backend._row_split(x)).compile()
     text = compiled.as_text()
-    assert "tpu_custom_call" not in text
-    assert "all-reduce" in text
+    assert text.startswith("HloModule jit__gram_matvec,")
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    # the kernel's op under its own name, as the device trace shows it
+    assert re.search(r"%gram_matvec_pallas[.\d]* = .*tpu_custom_call", text)
+    collectives = [(op, shape.split("{")[0]) for shape, op in
+                   COLLECTIVE.findall(text)]
+    assert collectives == [("all-reduce", "f32[8096]")], collectives
+    assert re.search(r"%all-reduce[.\d]* = ", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+
+
+@pytest.mark.parametrize("spec", [P(), P(None, "workers")])
+def test_gram_matvec_off_row_blocks_stays_two_pass(spec, topo):
+    """A full copy of X on each chip, or its columns split: no chip holds
+    whole rows of its own, and the path is XLA's two passes."""
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("workers",))
+    x = _spec((262_144, 8_096), NamedSharding(mesh, spec))
+    assert jax_backend._row_split(x) is None
+    assert _gram_path(x) == "xla"
 
 
 #: the 60,000-feature speech deployment on four chips: 94,208 rows of
 #: 440 features, 147 classes, Z = 94,208 x 60,000 float32 (22.6 GB)
 CG_ROWS, CG_FEATS, CG_CLASSES, CG_WIDTH = 94_208, 440, 147, 60_000
-COLLECTIVE = re.compile(r"= (\S+) (all-reduce|all-gather|reduce-scatter|"
-                        r"all-to-all|collective-permute)(?:-start)?\(")
 
 
 def _cg_programs(mesh):
@@ -296,4 +323,23 @@ def test_one_chip_cg_residual_is_unchanged(one_chip):
     text = _program_text(before)
     assert text.startswith("HloModule jit__cg_residual,")
     assert "ENTRY" in text and "while" in text and "stack_frame" not in text
+    assert _program_text(now) == text
+
+
+def test_one_chip_gram_matvec_is_unchanged(one_chip, compiled_kernels):
+    """On one chip (``ocean``: 193,536 x 8,096) the Gram matvec compiles
+    to the program it compiled to before the row-block path existed."""
+    @functools.partial(jax.jit, static_argnames="path")
+    def _gram_matvec(x, v, path: str = "xla"):
+        # the matvec before the row-block path, word for word, under its
+        # name (the program's)
+        return nm_ops.gram_matvec(x, v, path=path)
+
+    args = (_spec((193_536, 8_096), one_chip), _spec((8_096,), one_chip))
+    with jax.default_matmul_precision("highest"):
+        now = jax_backend._gram_matvec.lower(*args, path="cols").compile()
+        before = _gram_matvec.lower(*args, path="cols").compile()
+    text = _program_text(before)
+    assert text.startswith("HloModule jit__gram_matvec,")
+    assert "tpu_custom_call" in text
     assert _program_text(now) == text

@@ -1,12 +1,16 @@
 """Tests for the extension features: fused normal-matvec kernel, the
 one-pass Gram matvec kernel, NMF routine, offloaded linear-head
 fitting."""
+import types
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import PartitionSpec as P
 
 from repro.core import AlchemistContext
+from repro.core.backends import jax_backend
 from repro.core.libraries import elemental, skylark
 from repro.kernels.normal_matvec import ops as nm_ops
 from repro.kernels.normal_matvec.normal_matvec import normal_matvec_pallas
@@ -75,21 +79,43 @@ def test_gram_matvec_matches_ref(n, d):
 
 _MIB = 2 ** 20
 
+#: X's rows in blocks over four devices, a full copy on each, its
+#: columns split, and a 2-D split
+ROWS, REPLICATED, COLS, GRID = (P("workers", None), P(), P(None, "workers"),
+                                P("workers", "model"))
+
+
+def _split(spec):
+    """``(devices, row_blocks)`` for X over four devices by ``spec``, as
+    ``_truncated_svd`` reads them (``jax_backend._row_split``); one device
+    where ``spec`` is None."""
+    if spec is None:
+        return 1, False
+    x = types.SimpleNamespace(sharding=types.SimpleNamespace(
+        spec=spec, mesh="mesh", device_set=frozenset(range(4))))
+    return 4, jax_backend._row_split(x) is not None
+
 
 @pytest.mark.parametrize(
-    "d,dtype,compiled,devices,column_major,vmem,path", [
-        (8096, jnp.float32, True, 1, True, 128 * _MIB, "cols"),  # ocean
-        (8096, jnp.float32, True, 1, False, 128 * _MIB, "xla"),  # row-major
-        (8096, jnp.float32, False, 1, True, 0, "xla"),   # CPU: interpreted
-        (8096, jnp.float32, True, 4, True, 128 * _MIB, "xla"),   # a mesh
-        (32768, jnp.float32, True, 1, True, 128 * _MIB, "xla"),  # too wide
-        (8096, jnp.float32, True, 1, True, 64 * _MIB, "xla"),    # less VMEM
-        (4096, jnp.float32, True, 1, True, 64 * _MIB, "cols"),
-        (8096, jnp.bfloat16, True, 1, True, 128 * _MIB, "xla"),
+    "d,dtype,compiled,spec,column_major,vmem,path", [
+        (8096, jnp.float32, True, None, True, 128 * _MIB, "cols"),  # ocean
+        (8096, jnp.float32, True, None, False, 128 * _MIB, "xla"),  # row-maj
+        (8096, jnp.float32, False, None, True, 0, "xla"),   # CPU: interpreted
+        (8096, jnp.float32, True, ROWS, True, 128 * _MIB, "cols"),  # 4 chips
+        (8096, jnp.float32, True, REPLICATED, True, 128 * _MIB, "xla"),
+        (8096, jnp.float32, True, COLS, True, 128 * _MIB, "xla"),
+        (8096, jnp.float32, True, GRID, True, 128 * _MIB, "xla"),
+        (8096, jnp.float32, True, ROWS, False, 128 * _MIB, "xla"),
+        (32768, jnp.float32, True, None, True, 128 * _MIB, "xla"),  # too wide
+        (8096, jnp.float32, True, None, True, 64 * _MIB, "xla"),    # less VMEM
+        (4096, jnp.float32, True, None, True, 64 * _MIB, "cols"),
+        (8096, jnp.bfloat16, True, None, True, 128 * _MIB, "xla"),
     ])
-def test_gram_path_selection(d, dtype, compiled, devices, column_major,
+def test_gram_path_selection(d, dtype, compiled, spec, column_major,
                              vmem, path):
+    devices, row_blocks = _split(spec)
     assert nm_ops.gram_path(d, dtype, compiled=compiled, devices=devices,
+                            row_blocks=row_blocks,
                             column_major=column_major, vmem=vmem) == path
 
 

@@ -142,6 +142,14 @@ def test_cg_spans_carry_the_chips_z_spans(served_trace, name):
     assert ids[name] and all(i.get("shards") == 1 for i in ids[name])
 
 
+def test_lanczos_span_carries_the_path_and_shards(served_trace):
+    """The Lanczos span says which Gram matvec ran and over how many
+    devices X's rows lie: on the CPU, one device, XLA's two passes (four
+    virtual devices: ``test_svd_mesh4.py``)."""
+    _, _, _, ids = served_trace
+    assert ids[tracing.LANCZOS] == [{"path": "xla", "shards": 1}]
+
+
 def test_every_request_frame_has_a_server_span():
     requests = [s for s in wire.FRAME_SPECS if s.role == "request"]
     assert _Connection._SPANS == {
