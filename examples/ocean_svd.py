@@ -1,7 +1,6 @@
 """Paper §4.2 end-to-end: rank-20 truncated SVD of an ocean-temperature-like
 field, three use cases (Table 5) plus the Fig. 3 weak-scaling column
-replication — at CPU scale, with the modeled cluster-scale numbers printed
-alongside the paper's.
+replication — at CPU scale, with the paper's times printed alongside.
 
     PYTHONPATH=src python examples/ocean_svd.py
 """
@@ -10,7 +9,6 @@ import time
 import numpy as np
 
 from repro.core import AlchemistContext
-from repro.core.costmodel import socket_transfer_seconds
 from repro.core.libraries import elemental, mllib
 from repro.frontend.rowmatrix import RowMatrix
 
@@ -51,8 +49,7 @@ def main():
     print(f"[case 2] spark-load + alchemist-SVD: {t2:.2f}s measured "
           f"  paper: 121.9s (4.5x)")
     print("         (both substrates share this CPU: measured parity is "
-          "expected; the cluster-scale gap comes from the modeled BSP "
-          "overhead, see benchmarks table5)")
+          "expected)")
 
     # use case 3: engine loads and computes — the two stages chain
     # lazily (one submit each, the SVD riding a dependency edge)
@@ -78,11 +75,6 @@ def main():
         t = time.perf_counter() - t0
         print(f"  x{times}: {t:.2f}s -> weak-scaled wall "
               f"(t/x) = {t / times:.2f}s")
-
-    # modeled 400GB transfer (the paper's dominant case-2 overhead)
-    m = socket_transfer_seconds(6_177_583 * 8_096 * 8, 320, 384)
-    print(f"\nmodeled 400GB socket transfer at paper's allocation: {m:.0f}s "
-          "(paper measured 62.5s)")
     ac.stop()
 
 

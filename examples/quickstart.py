@@ -36,9 +36,8 @@ def main():
         al_a = ac.send_matrix(a)                # val alA = AlMatrix(A)
         rec = al_a.last_transfer
         print(f"sent {al_a.shape} -> handle #{al_a.handle.id} in "
-              f"{rec.num_chunks} streamed chunk(s); modeled socket cost "
-              f"{rec.modeled_socket_s:.3f}s, TPU reshard cost "
-              f"{rec.modeled_reshard_s * 1e6:.1f}us")
+              f"{rec.num_chunks} streamed chunk(s), {rec.nbytes} bytes "
+              f"({rec.wire_nbytes} framed on a socket; none in process)")
 
         # QRDecomposition(alA) — outputs tuple-unpack in declared order,
         # lazily: nothing waits until a proxy is forced

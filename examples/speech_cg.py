@@ -83,9 +83,7 @@ def main():
     print(f"[spark]     solve {t_spark:.2f}s measured "
           f"({stats['iterations']} iters, {stats['bsp_rounds']} BSP rounds)")
     print("NOTE: both substrates share this CPU, so measured times are not "
-          "the cluster story; the paper-calibrated model at 30 nodes/10k "
-          f"features gives spark {1388 / 30 + 5.9:.1f}s/iter vs alchemist "
-          f"{52 / 30 + 0.2:.1f}s/iter (~26x).")
+          "the cluster story.")
     agree = np.abs(w - w_spark).max() / np.abs(w_spark).max()
     print(f"solutions agree to {agree:.1e} (same math, different substrate)")
     ac.stop()

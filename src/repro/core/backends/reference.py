@@ -283,8 +283,7 @@ def _nmf(A, k: int, max_iters: int = 100, seed: int = 0, eps: float = 1e-9):
 # mllib — shared row-partitioned baseline (backend-invariant by design)
 # ---------------------------------------------------------------------------
 def mllib_cg_solve(X, Y, lam: float = 1e-5, max_iters: int = 200,
-                   tol: float = 1e-8, nodes: int = 20,
-                   num_partitions: int = 8):
+                   tol: float = 1e-8, num_partitions: int = 8):
     """The pure-Spark CG baseline driven through the ABI: rebuild the
     row-partitioned RowMatrix and run the identical BSP-round math. The
     jax backend registers this same function — the baseline measures a
@@ -293,17 +292,17 @@ def mllib_cg_solve(X, Y, lam: float = 1e-5, max_iters: int = 200,
     x = RowMatrix.from_array(np.asarray(X), num_partitions)
     y = RowMatrix.from_array(np.asarray(Y), num_partitions)
     w, stats = mllib.spark_cg_solve(x, y, lam=lam, max_iters=max_iters,
-                                    tol=tol, nodes=nodes)
+                                    tol=tol)
     return {"W": np.asarray(w, np.float32), **stats}
 
 
-def mllib_truncated_svd(A, k: int, oversample: int = 32, nodes: int = 12,
-                        seed: int = 0, num_partitions: int = 8):
+def mllib_truncated_svd(A, k: int, oversample: int = 32, seed: int = 0,
+                        num_partitions: int = 8):
     """The MLlib-style Lanczos SVD baseline through the ABI (see
     :func:`mllib_cg_solve` for why both backends share it)."""
     x = RowMatrix.from_array(np.asarray(A), num_partitions)
     sigma, v, stats = mllib.spark_truncated_svd(
-        x, k=k, oversample=oversample, nodes=nodes, seed=seed)
+        x, k=k, oversample=oversample, seed=seed)
     return {"S": np.asarray(sigma, np.float32),
             "V": np.asarray(v, np.float32), **stats}
 

@@ -24,7 +24,7 @@ here:
   them) and the fingerprint is looked up in the engine's store index
   before any byte crosses. A re-upload of an already-resident matrix —
   the repeated-tenant case — short-circuits to a handle *alias* with a
-  zero-byte modeled crossing.
+  zero-byte logged crossing.
 
 Fingerprints are strings with a namespace prefix so the three origins can
 never collide:

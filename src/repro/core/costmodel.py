@@ -1,19 +1,17 @@
-"""Calibrated cost models of the paper's measured overheads.
+"""Live accounting of the served path, and the QoS price.
 
-This repo runs on a single CPU host, so cluster-scale wall-times cannot be
-measured. Following the paper's own accounting (transfer vs compute,
-Tables 2-5), we model:
+The logs record what the engine, the scheduler and the bridges actually
+did, each under its own lock: :class:`TransferLog` (every chunk that
+crossed the bridge, its logical and framed bytes), :class:`WireLog`
+(frames and bytes per socket endpoint), :class:`TaskLog` (queue wait and
+execute time per task), :class:`CompileLog` (plan compiles and cache
+hits), :class:`JitLog` (every executable JAX obtains in the process),
+:class:`QosLog` (admission, backpressure and preemption) and
+:class:`CacheLog` (memoized hits and upload dedup).
 
-  * client->engine transfer time as a function of (bytes, client procs,
-    engine procs), calibrated to Table 3 (2,251,569 x 10,000 fp64 ~ 180GB);
-  * Spark's per-iteration BSP overhead vs Alchemist's, calibrated to
-    Table 2 (CG on the 10k-feature TIMIT system);
-  * on the TPU adaptation, the same role is played by the client-mesh ->
-    engine-mesh reshard: bytes / (ICI/DCN bandwidth), reported separately.
-
-All benchmark tables print measured-small-scale numbers AND these modeled
-cluster-scale numbers side by side with the paper's measurements, so the
-calibration error is always visible.
+The one estimate here is the QoS price, :func:`routine_price_seconds`
+with :data:`TASK_DISPATCH_S`: the fair-share policy charges it at
+dispatch and reconciles it against the measured ``exec_s``.
 """
 from __future__ import annotations
 
@@ -23,131 +21,12 @@ import time
 
 from repro.analysis import locktrace
 
-GB = 1e9
-
-# ---- Table 3 calibration (socket transfer, Cori Phase 1) ----
-# Effective aggregate rate grows sublinearly with the narrower side of the
-# bridge (shared NICs): rate ~ C * min(procs)^P GB/s. Fit to the paper's
-# (2,20)->580.1s and (20,20)->149.5s cells of Table 3 (180GB matrix); the
-# remaining cells scatter +/-2x around this due to network load (the paper
-# itself reports 3-run averages with large variability).
-_RATE_C = 0.206
-_RATE_P = 0.588
-_IMBALANCE = 0.0
-
-# ---- Table 2 calibration (per-iteration CG cost, 10k features) ----
-# t_iter(nodes) = A / nodes + B   [seconds], fit to the paper's 20/30/40-node
-# measurements; scaled linearly in FLOPs for other problem sizes.
-_SPARK_A, _SPARK_B = 1388.0, 5.9          # Spark BSP (scheduler+task overhead)
-_ALCH_A, _ALCH_B = 52.0, 0.2              # Alchemist (C+MPI via Elemental)
-_CAL_FEATURES = 10_000                    # calibration problem size
-_CAL_ROWS = 2_251_569
-
-# ---- TPU adaptation constants ----
-ICI_BW = 50e9                             # bytes/s per link
-DCN_BW = 25e9                             # bytes/s per host, cross-slice
-
-# ---- streaming (chunked) transfer constants ----
-# The paper's sends are socket-buffered: each row-block message pays a fixed
-# per-message cost (syscall + TCP round trip + Elemental re-layout staging)
-# before the payload streams at the Table-3 rate. Small chunks are overhead
-# bound; large chunks lose send/receive pipelining. 2019's Cray follow-up
-# (Rothauge et al.) reports exactly this trade-off when tuning buffer sizes.
-CHUNK_LATENCY_S = 2.5e-4                  # per-chunk fixed cost, seconds
-PIPELINE_FRACTION = 0.35                  # overlap of send with re-layout
-
-# ---- task-dispatch constant (backend fusion model) ----
-# Modeled fixed cost of dispatching ONE scheduler task: queue insertion,
+# ---- task-dispatch constant (QoS price) ----
+# Estimated fixed cost of dispatching ONE scheduler task: queue insertion,
 # hazard-edge bookkeeping, worker wakeup, result encode, and the XLA
-# dispatch itself. This is what chain fusion amortizes: an N-op chain
-# executed eagerly pays it N times, fused it pays it once (plus the same
-# N submit crossings the lazy client already pays either way).
-# Ballpark of the measured per-task scheduler overhead on this container;
-# benchmarks print measured numbers next to anything modeled with it.
+# dispatch itself. The QoS price adds it to every routine's estimate, and
+# admission and fair share use it as the floor of any estimate.
 TASK_DISPATCH_S = 2.0e-4
-
-
-def socket_transfer_seconds(nbytes: int, client_procs: int,
-                            engine_procs: int) -> float:
-    """Modeled Spark->Alchemist TCP transfer time (paper Table 3)."""
-    lo, hi = sorted((max(1, client_procs), max(1, engine_procs)))
-    rate = _RATE_C * lo ** _RATE_P
-    penalty = 1.0 + _IMBALANCE * (hi / lo - 1.0)
-    return nbytes / GB / rate * penalty
-
-
-def stream_transfer_seconds(nbytes: int, chunk_bytes: int,
-                            client_procs: int, engine_procs: int) -> float:
-    """Modeled chunked-socket transfer time (§3.2 streaming path).
-
-    ``nbytes`` total payload split into ``chunk_bytes`` messages: each pays
-    :data:`CHUNK_LATENCY_S`, while chunking overlaps the wire send with the
-    engine-side re-layout for every chunk except the last (the
-    :data:`PIPELINE_FRACTION` discount). Minimized at a mid-size chunk —
-    the sweep in ``benchmarks/table3_transfer.py`` exposes the curve.
-
-    This is the *uniform-chunk* form (the what-if knob the Table-3 sweep
-    turns); for a stream that actually crossed, model from its real
-    chunk-size list with :func:`stream_transfer_seconds_from_chunks` —
-    shard-boundary cuts produce runt chunks that a mean-size model
-    mis-prices.
-    """
-    chunk_bytes = max(1, int(chunk_bytes))
-    num_chunks = max(1, -(-int(nbytes) // chunk_bytes))
-    wire = socket_transfer_seconds(nbytes, client_procs, engine_procs)
-    if num_chunks > 1:
-        wire *= 1.0 - PIPELINE_FRACTION * (num_chunks - 1) / num_chunks
-    return num_chunks * CHUNK_LATENCY_S + wire
-
-
-def stream_chunk_seconds(chunk_nbytes: int, client_procs: int,
-                         engine_procs: int, pipelined: bool = False) -> float:
-    """Modeled cost of ONE chunk of a §3.2 stream: the fixed per-message
-    latency plus the chunk's wire time, discounted by
-    :data:`PIPELINE_FRACTION` when its send overlaps the engine-side
-    re-layout (every chunk of a stream except the last)."""
-    wire = socket_transfer_seconds(chunk_nbytes, client_procs, engine_procs)
-    if pipelined:
-        wire *= 1.0 - PIPELINE_FRACTION
-    return CHUNK_LATENCY_S + wire
-
-
-def stream_transfer_seconds_from_chunks(chunk_sizes, client_procs: int,
-                                        engine_procs: int) -> float:
-    """Stream model over the *actual* chunk-size list of a crossing.
-
-    Equals :func:`stream_transfer_seconds` when chunks are uniform, and —
-    by construction — always equals the sum of the per-chunk
-    :func:`stream_chunk_seconds` records the transfer layer logs, so a
-    stream's aggregate record agrees with its per-chunk records even when
-    shard-boundary cuts leave runt chunks.
-    """
-    sizes = list(chunk_sizes)
-    n = len(sizes)
-    return sum(
-        stream_chunk_seconds(c, client_procs, engine_procs,
-                             pipelined=(i < n - 1))
-        for i, c in enumerate(sizes))
-
-
-def spark_cg_iteration_seconds(nodes: int, rows: int, features: int) -> float:
-    """Modeled Spark per-CG-iteration cost (paper Table 2 calibration)."""
-    scale = (rows * features) / (_CAL_ROWS * _CAL_FEATURES)
-    return (_SPARK_A / nodes + _SPARK_B) * scale
-
-
-def alchemist_cg_iteration_seconds(nodes: int, rows: int,
-                                   features: int) -> float:
-    """Modeled Alchemist (C+MPI) per-CG-iteration cost (Table 2/4)."""
-    scale = (rows * features) / (_CAL_ROWS * _CAL_FEATURES)
-    return (_ALCH_A / nodes + _ALCH_B) * scale
-
-
-def reshard_transfer_seconds(nbytes: int, chips: int,
-                             cross_pod: bool = False) -> float:
-    """TPU-native analogue: client-mesh -> engine-mesh re-layout cost."""
-    bw = DCN_BW if cross_pod else ICI_BW
-    return nbytes / (chips * bw)
 
 
 @dataclasses.dataclass
@@ -159,13 +38,11 @@ class TransferRecord:
     marks a whole-stream *aggregate* record (what ``transfer.to_engine``/
     ``to_client`` return to the caller; never appended to the log — with
     one exception: a content-dedup'd upload produces a single aggregate
-    record with ``dedup=True``, zero ``nbytes`` and zero modeled cost,
-    which IS logged, because that zero-byte crossing is the whole event;
+    record with ``dedup=True`` and zero ``nbytes``, which IS logged,
+    because that zero-byte crossing is the whole event;
     ``logical_nbytes`` records what the stream would have moved)."""
     nbytes: int
     direction: str                # "to_engine" | "to_client"
-    modeled_socket_s: float
-    modeled_reshard_s: float
     session: int = 0
     chunk_index: int = 0
     num_chunks: int = 1
@@ -173,51 +50,32 @@ class TransferRecord:
     logical_nbytes: int = 0       # bytes the dedup'd stream did NOT move
     # Bytes actually framed onto a TCP socket for this crossing (frame
     # headers + serialized payload). 0 on the in-memory bridge — there is
-    # no wire — and measured, not modeled, on the socket bridge; ``nbytes``
-    # always keeps the logical payload size so the two bridges stay
-    # directly comparable.
+    # no wire — and measured on the socket bridge; ``nbytes`` always
+    # keeps the logical payload size so the two bridges stay directly
+    # comparable.
     wire_nbytes: int = 0
 
 
 class TransferLog:
-    """Accumulates every boundary crossing for the EXPERIMENTS tables.
+    """Accumulates every boundary crossing.
 
     Appends are lock-protected: with the async scheduler, transfers from
     several client threads interleave with engine-side task execution, and
     the log is the shared accounting surface they all write.
     """
 
-    def __init__(self, client_procs: int = 20, engine_procs: int = 20,
-                 chips: int = 256):
-        self.client_procs = client_procs
-        self.engine_procs = engine_procs
-        self.chips = chips
+    def __init__(self):
         self.records: list[TransferRecord] = []
         self._lock = locktrace.make_lock("costmodel.transfer")
 
     def record(self, nbytes: int, direction: str, session: int = 0,
                chunk_index: int = 0, num_chunks: int = 1,
-               pipelined=None, wire_nbytes: int = 0) -> TransferRecord:
+               wire_nbytes: int = 0) -> TransferRecord:
         """Log one crossing (one chunk of a streamed send, or a whole
-        single-shot send) and return the record with its modeled costs.
-
-        ``pipelined=None`` prices a single-shot send with the plain socket
-        model; a bool marks the record as one chunk of a stream and prices
-        it with :func:`stream_chunk_seconds` (per-message latency, and the
-        pipeline discount when True) — so a stream's per-chunk records sum
-        exactly to its aggregate."""
-        if pipelined is None:
-            socket_s = socket_transfer_seconds(
-                nbytes, self.client_procs, self.engine_procs)
-        else:
-            socket_s = stream_chunk_seconds(
-                nbytes, self.client_procs, self.engine_procs,
-                pipelined=pipelined)
+        single-shot send) and return its record."""
         rec = TransferRecord(
             nbytes=int(nbytes),
             direction=direction,
-            modeled_socket_s=socket_s,
-            modeled_reshard_s=reshard_transfer_seconds(nbytes, self.chips),
             session=session,
             chunk_index=chunk_index,
             num_chunks=num_chunks,
@@ -231,13 +89,12 @@ class TransferLog:
                      session: int = 0, num_chunks: int = 1,
                      wire_nbytes: int = 0) -> TransferRecord:
         """Log a content-dedup'd upload: the stream short-circuited to a
-        handle alias, so zero bytes and zero modeled seconds actually
-        crossed; ``logical_nbytes`` is what the stream would have moved
-        (over a socket, ``wire_nbytes`` is the tiny fingerprint-lookup
-        frame — never the payload)."""
+        handle alias, so zero bytes actually crossed; ``logical_nbytes``
+        is what the stream would have moved (over a socket,
+        ``wire_nbytes`` is the tiny fingerprint-lookup frame — never the
+        payload)."""
         rec = TransferRecord(
-            nbytes=0, direction=direction, modeled_socket_s=0.0,
-            modeled_reshard_s=0.0, session=session, chunk_index=-1,
+            nbytes=0, direction=direction, session=session, chunk_index=-1,
             num_chunks=num_chunks, dedup=True,
             logical_nbytes=int(logical_nbytes),
             wire_nbytes=int(wire_nbytes))
@@ -249,22 +106,12 @@ class TransferLog:
     def total_bytes(self) -> int:
         return sum(r.nbytes for r in self.records)
 
-    @property
-    def total_socket_seconds(self) -> float:
-        return sum(r.modeled_socket_s for r in self.records)
-
-    def session_bytes(self, session: int) -> int:
-        """Total bytes a given client session moved across the bridge."""
-        return sum(r.nbytes for r in self.records if r.session == session)
-
     def session_summary(self, session: int) -> dict:
         """Per-session transfer accounting: bytes and chunk counts by
-        direction plus total modeled socket seconds — what the
-        multi-client benchmark charges each tenant for bridge traffic."""
+        direction, and the uploads dedup short-circuited."""
         with self._lock:
             recs = [r for r in self.records if r.session == session]
-        out = {"session": session,
-               "modeled_socket_s": sum(r.modeled_socket_s for r in recs)}
+        out = {"session": session}
         for direction in ("to_engine", "to_client"):
             sub = [r for r in recs if r.direction == direction]
             out[f"{direction}_bytes"] = sum(r.nbytes for r in sub)
@@ -280,21 +127,13 @@ class TransferLog:
 
 @dataclasses.dataclass
 class WireStat:
-    """Measured (not modeled) traffic of one wire endpoint: how many
-    frames crossed in each direction and how many bytes they occupied on
-    the socket, frame headers included."""
+    """Measured traffic of one wire endpoint: how many frames crossed in
+    each direction and how many bytes they occupied on the socket, frame
+    headers included."""
     frames_in: int = 0
     bytes_in: int = 0
     frames_out: int = 0
     bytes_out: int = 0
-
-    @property
-    def frames(self) -> int:
-        return self.frames_in + self.frames_out
-
-    @property
-    def bytes(self) -> int:
-        return self.bytes_in + self.bytes_out
 
 
 class WireLog:
@@ -304,8 +143,7 @@ class WireLog:
     submit is one crossing however it is carried — and that stays true on
     every bridge. This log is the physical complement: the socket server
     (and the client bridge) record here how many frames each logical call
-    actually cost and how many bytes they put on the wire, so the
-    transfer tables can report protocol overhead instead of assuming it.
+    actually cost and how many bytes they put on the wire.
     The in-memory bridge never writes one: no socket, no frames.
     """
 
@@ -327,25 +165,10 @@ class WireLog:
         with self._lock:
             return self._stats.get(endpoint, WireStat())
 
-    def stats(self) -> dict[str, WireStat]:
-        """Snapshot of every endpoint's stat (copy — safe to iterate)."""
-        with self._lock:
-            return dict(self._stats)
-
-    @property
-    def total_frames(self) -> int:
-        with self._lock:
-            return sum(s.frames for s in self._stats.values())
-
-    @property
-    def total_bytes(self) -> int:
-        with self._lock:
-            return sum(s.bytes for s in self._stats.values())
-
 
 def percentile(values, q: float) -> float:
     """Nearest-rank percentile (q in [0, 100]) without numpy — the latency
-    quantile the benchmark tables report. Returns 0.0 on empty input."""
+    quantile the logs' summaries report. Returns 0.0 on empty input."""
     if not values:
         return 0.0
     vals = sorted(values)
@@ -414,7 +237,7 @@ class TaskLog:
 
     def stats(self) -> dict:
         """Engine-wide dispatch/fusion/relayout accounting — what the
-        fusion benchmark and tests assert on.
+        fusion tests assert on.
 
         ``commands`` counts logical routine invocations (every recorded
         row); ``dispatched`` counts tasks that actually ran on a worker
@@ -491,10 +314,8 @@ class CompileLog:
     queue-vs-execute time, this log shows the third hidden term the
     paper's overhead argument warns about: XLA trace+compile seconds of
     the programs the engine plans, and *where* they were paid (on a
-    tenant's first call, or off-path during warmup). The smoke gate in
-    ``benchmarks/compile_warmup.py`` asserts directly on
-    :meth:`stats`: after warmup, ``request_compiles`` for bucketed
-    shapes must be zero.
+    tenant's first call, or off-path during warmup). After warmup,
+    :meth:`stats` reports zero ``request_compiles`` for bucketed shapes.
 
     ``compiles`` counts only those plan compiles. A ``jax.jit`` that a
     routine body builds or calls itself (the host loops' matvec and CG
@@ -807,8 +628,7 @@ class CacheLog:
 
     def session_summary(self, session: int) -> dict:
         """Hit/miss/dedup counts, hit rate, and saved seconds/bytes for
-        one client session — what the multi-tenant cache benchmark charges
-        (or rather, credits) each tenant."""
+        one client session."""
         with self._lock:
             recs = [r for r in self.records if r.session == session]
         return {"session": session, **self._summarize(recs)}

@@ -255,7 +255,6 @@ class AlchemistEngine:
     """
 
     def __init__(self, mesh: Optional[Mesh] = None,
-                 transfer_log: Optional[TransferLog] = None,
                  memory_budget_bytes: Optional[int] = None,
                  scheduler_workers: int = 4,
                  cache_entries: int = 256,
@@ -296,11 +295,10 @@ class AlchemistEngine:
         self._catalogs: dict[str, dict[str, dict]] = {
             ENGINE_LIBRARY: specs.catalog_to_wire(self._BUILTINS)}
         # client<->engine crossings per wire endpoint — what the chain-
-        # pipelining benchmark counts to prove a lazy chain submits with
-        # zero intermediate round trips
+        # pipelining tests count to prove a lazy chain submits with zero
+        # intermediate round trips
         self.endpoint_counts: collections.Counter = collections.Counter()
-        self.transfer_log = transfer_log or TransferLog(
-            engine_procs=self.num_workers)
+        self.transfer_log = TransferLog()
         self.task_log = TaskLog()
         # the content-addressed routine cache (0 entries disables
         # memoization; the transfer-dedup fingerprint index stays on —

@@ -1,13 +1,11 @@
 """The "pure Spark" baseline — MLlib-style implementations restricted to the
-client's row-partitioned layout, with the paper's measured BSP overheads.
+client's row-partitioned layout.
 
 The paper's comparison baseline (its Tables 2/5 'Spark' rows) runs the same
 algorithms inside Spark: every CG iteration / Lanczos matvec is a
 treeAggregate over row partitions, paying scheduler + task-launch overhead
 per BSP round. We implement the identical math over RowMatrix partitions
-(measured) and model the per-round overhead with the Table-2 calibration
-(see core/costmodel.py) — both numbers are reported separately by the
-benchmarks so measurement and model never blur.
+and report its measured wall time and BSP round count.
 
 Unlike the ALI modules (elemental/skylark) the *direct entry points*
 (``spark_cg_solve``/``spark_truncated_svd``) never touch the engine or a
@@ -33,20 +31,18 @@ import time
 
 import numpy as np
 
-from repro.core.costmodel import spark_cg_iteration_seconds
 from repro.core.libraries.spec import routine, spec_only
 from repro.frontend.rowmatrix import RowMatrix
 
 
 def spark_cg_solve(x: RowMatrix, y: RowMatrix, lam: float = 1e-5,
-                   max_iters: int = 200, tol: float = 1e-8,
-                   nodes: int = 20):
+                   max_iters: int = 200, tol: float = 1e-8):
     """CG on the normal equations, one BSP round per iteration.
 
-    Returns (W, stats) where stats carries measured wall time, BSP round
-    count, and the modeled cluster-scale per-iteration cost.
+    Returns (W, stats) where stats carries measured wall time and BSP
+    round count.
     """
-    n, d = x.shape
+    n = x.shape[0]
     b = x.t_times(y)                             # X^T Y  (one BSP round)
     b_norm = np.linalg.norm(b, axis=0)
     w = np.zeros_like(b)
@@ -76,18 +72,16 @@ def spark_cg_solve(x: RowMatrix, y: RowMatrix, lam: float = 1e-5,
         "bsp_rounds": rounds,
         "relative_residual": rel,
         "measured_seconds": measured,
-        "modeled_iteration_seconds": spark_cg_iteration_seconds(
-            nodes, n, d),
     }
     return w, stats
 
 
 def spark_truncated_svd(x: RowMatrix, k: int, oversample: int = 32,
-                        nodes: int = 12, seed: int = 0):
+                        seed: int = 0):
     """MLlib-style truncated SVD: Lanczos on the Gram matrix where each
     matvec is a distributed treeAggregate over row partitions (MLlib's
     computeSVD does exactly this via ARPACK)."""
-    n, d = x.shape
+    d = x.shape[1]
     m = min(d, k + oversample)
     rng = np.random.RandomState(seed)
     q = rng.randn(d)
@@ -125,11 +119,6 @@ def spark_truncated_svd(x: RowMatrix, k: int, oversample: int = 32,
     stats = {
         "bsp_rounds": rounds,
         "measured_seconds": measured,
-        # same Table-2 calibration as CG: the modeled cost of ONE BSP
-        # round (matvec treeAggregate) at cluster scale, not an overhead
-        # delta — hence the same key name as spark_cg_solve's
-        "modeled_iteration_seconds": spark_cg_iteration_seconds(
-            nodes, n, d),
         "lanczos_iters": int(m),
     }
     return sigma, V, stats
@@ -138,8 +127,7 @@ def spark_truncated_svd(x: RowMatrix, k: int, oversample: int = 32,
 # ---- ALI-hosted declarations (the describable catalog surface) ------------
 @routine(outputs=("W",))
 def _ali_cg_solve(engine, X, Y, lam: float = 1e-5, max_iters: int = 200,
-                  tol: float = 1e-8, nodes: int = 20,
-                  num_partitions: int = 8):
+                  tol: float = 1e-8, num_partitions: int = 8):
     """The pure-Spark CG baseline, driven through the ALI calling
     convention: handles resolve to the resident arrays, the identical
     row-partitioned math runs (one simulated BSP round per iteration),
@@ -150,8 +138,7 @@ def _ali_cg_solve(engine, X, Y, lam: float = 1e-5, max_iters: int = 200,
 
 @routine(outputs=("S", "V"))
 def _ali_truncated_svd(engine, A, k: int, oversample: int = 32,
-                       nodes: int = 12, seed: int = 0,
-                       num_partitions: int = 8):
+                       seed: int = 0, num_partitions: int = 8):
     """The MLlib-style Lanczos SVD baseline through the ALI calling
     convention (see :func:`_ali_cg_solve`): returns the top-k singular
     values and right singular vectors as engine handles."""

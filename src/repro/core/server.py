@@ -374,7 +374,6 @@ class _Connection:
                 self.engine.transfer_log.record(
                     piece.nbytes, "to_engine", session=up.session,
                     chunk_index=seq, num_chunks=up.num_chunks,
-                    pipelined=(seq < up.num_chunks - 1),
                     wire_nbytes=frame_len)
         except Exception as e:
             if up is not None:
@@ -422,8 +421,7 @@ class _Connection:
                     wire_nbytes=up.wire_bytes)
             else:
                 rec = transfer._aggregate_record(
-                    self.engine.transfer_log, sum(up.sizes), "to_engine",
-                    session, up.sizes)
+                    sum(up.sizes), "to_engine", session, len(up.sizes))
                 rec.wire_nbytes = up.wire_bytes
             reply = protocol.encode_result(protocol.Result(
                 values={"handle": handle,
@@ -486,7 +484,6 @@ class _Connection:
             {"shape": list(arr.shape), "dtype": str(arr.dtype),
              "whole": False, "psizes": psizes,
              "num_partitions": num_partitions}))
-        sizes: list[int] = []
         total = 0
         wire_total = 0
         read = transfer.row_reader(arr)
@@ -496,15 +493,12 @@ class _Connection:
                                   "array": wire.pack_ndarray(block)})
             frame_len = wire.HEADER_BYTES + len(body)
             total += block.nbytes
-            sizes.append(block.nbytes)
             wire_total += frame_len
             log.record(block.nbytes, "to_client", session=sess,
                        chunk_index=idx, num_chunks=len(plan),
-                       pipelined=(idx < len(plan) - 1),
                        wire_nbytes=frame_len)
             self._send_frame("fetch", wire.FRAME_FETCH_CHUNK, body)
-        rec = transfer._aggregate_record(log, total, "to_client", sess,
-                                         sizes)
+        rec = transfer._aggregate_record(total, "to_client", sess, len(plan))
         rec.wire_nbytes = wire_total
         self._send_frame("fetch", wire.FRAME_FETCH_END, msgpack.packb(
             {"record": dataclasses.asdict(rec)}))

@@ -9,15 +9,13 @@ mirrors that: a matrix crosses the bridge as a sequence of row-block
 client memory is one partition plus one chunk, never the whole matrix),
 each chunk is ``device_put`` directly onto the engine device that owns its
 row range, and each chunk logs its own
-:class:`~repro.core.costmodel.TransferRecord`, so the cost model — and
-``benchmarks/table3_transfer.py``'s chunk-size sweep — sees the same
-per-message structure the real sockets have.
+:class:`~repro.core.costmodel.TransferRecord`, so the transfer log sees
+the same per-message structure the real sockets have.
 
-Chunk sizing and the cost models use the *source's actual dtype*
-(``RowMatrix.dtype`` is tracked client-side exactly for this): a float32
-matrix has half the row-bytes of a float64 one, so assuming 8-byte
-elements — as this layer once did — doubles chunk sizes and doubles the
-modeled socket cost.
+Chunk sizing uses the *source's actual dtype* (``RowMatrix.dtype`` is
+tracked client-side exactly for this): a float32 matrix has half the
+row-bytes of a float64 one, so assuming 8-byte elements — as this layer
+once did — doubles chunk sizes.
 
 **Upload dedup** (``dedup=True``, the default): the matrix's bytes are
 digested in row-major order (chunk-boundary invariant — the same bytes
@@ -25,8 +23,8 @@ dedup whatever ``chunk_rows`` carried them) and the fingerprint is looked
 up in the engine's store index. A re-upload of already-resident content —
 the repeated-tenant case of the Cray deployment report — never streams:
 the engine mints a handle *alias* over the existing store, and the log
-records a zero-byte, zero-second crossing (``TransferRecord.dedup``) with
-the avoided payload in ``logical_nbytes``.
+records a zero-byte crossing (``TransferRecord.dedup``) with the avoided
+payload in ``logical_nbytes``.
 
 The pre-stream hash pass walks the source once more than a plain upload —
 cheap for ndarrays (slices are views) and *cached* RowMatrix RDDs
@@ -37,13 +35,7 @@ even reproduce the bytes the fingerprint was built from — so such uploads
 skip the pre-stream lookup and hash inline *during* streaming instead:
 the registered fingerprint always matches the bytes that actually
 crossed, and later uploads of equal content still dedup against it. Pass
-``dedup=False`` to skip hashing entirely (the Table-3 bandwidth sweep
-does).
-
-On a TPU system both "sides" are device meshes, so the socket send becomes
-an explicit re-layout; the cost model records what the same movement would
-cost over the paper's sockets and over ICI/DCN, feeding the EXPERIMENTS
-transfer tables.
+``dedup=False`` to skip hashing entirely.
 """
 from __future__ import annotations
 
@@ -55,11 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import cache as caching, tracing
-from repro.core.costmodel import (
-    TransferRecord,
-    reshard_transfer_seconds,
-    stream_transfer_seconds_from_chunks,
-)
+from repro.core.costmodel import TransferRecord
 from repro.core.engine import SYSTEM_SESSION, AlchemistEngine
 from repro.core.handles import MatrixHandle
 from repro.frontend.rowmatrix import RowMatrix
@@ -148,22 +136,17 @@ def _row_blocks(arr) -> Optional[list[tuple[int, int, Any]]]:
     return list(blocks.values())
 
 
-def _aggregate_record(log, nbytes: int, direction: str, session: int,
-                      chunk_sizes: list[int]) -> TransferRecord:
+def _aggregate_record(nbytes: int, direction: str, session: int,
+                      num_chunks: int) -> TransferRecord:
     """Whole-stream summary record (returned to the caller, NOT logged —
     the log carries the per-chunk records). ``chunk_index=-1`` marks it as
-    an aggregate. Modeled from the stream's *actual* chunk-size list, so
-    it equals the sum of the per-chunk records by construction — a mean
-    chunk size would disagree whenever shard-boundary cuts leave runts."""
+    an aggregate."""
     return TransferRecord(
         nbytes=int(nbytes),
         direction=direction,
-        modeled_socket_s=stream_transfer_seconds_from_chunks(
-            chunk_sizes, log.client_procs, log.engine_procs),
-        modeled_reshard_s=reshard_transfer_seconds(nbytes, log.chips),
         session=session,
         chunk_index=-1,
-        num_chunks=len(chunk_sizes),
+        num_chunks=num_chunks,
     )
 
 
@@ -188,7 +171,7 @@ def to_engine(engine: AlchemistEngine, matrix, name: Optional[str] = None,
     alias with a zero-byte logged crossing (see module docstring).
 
     Returns ``(handle, aggregate record)`` — the record summarizes the
-    whole stream (total bytes, chunk count, stream-modeled socket cost);
+    whole stream (total bytes, chunk count);
     the per-chunk records live in ``engine.transfer_log``.
 
     A ``jax.Array`` input is already device-resident (an engine-side
@@ -290,18 +273,15 @@ def to_engine(engine: AlchemistEngine, matrix, name: Optional[str] = None,
         inline_hasher = caching.ContentHasher(shape, dtype)
 
     per_range: list[list[jax.Array]] = [[] for _ in ranges]
-    sizes: list[int] = []
     total = 0
     for idx, ((lo, hi), chunk) in enumerate(zip(plan, chunk_stream())):
         chunk = np.ascontiguousarray(chunk)
         if inline_hasher is not None:
             inline_hasher.update(chunk)
         total += chunk.nbytes
-        sizes.append(chunk.nbytes)
         engine.transfer_log.record(
             chunk.nbytes, "to_engine", session=session,
-            chunk_index=idx, num_chunks=num_chunks,
-            pipelined=(idx < num_chunks - 1))
+            chunk_index=idx, num_chunks=num_chunks)
         r = bisect.bisect_right(starts, lo) - 1 if partitioned else 0
         per_range[r].append(jax.device_put(chunk, ranges[r][2]))
 
@@ -314,8 +294,7 @@ def to_engine(engine: AlchemistEngine, matrix, name: Optional[str] = None,
         arr = jax.device_put(shards[0], sharding)
     if inline_hasher is not None:
         fingerprint = inline_hasher.fingerprint()
-    rec = _aggregate_record(
-        engine.transfer_log, total, "to_engine", session, sizes)
+    rec = _aggregate_record(total, "to_engine", session, num_chunks)
     return engine.put(arr, name=name, session=session,
                       fingerprint=fingerprint), rec
 
@@ -447,7 +426,6 @@ def to_client(engine: AlchemistEngine, handle: MatrixHandle,
 
     plan = _row_plan(rows, chunk_rows, pstarts[1:-1])
     blocks: list[Optional[np.ndarray]] = [None] * num_partitions
-    sizes: list[int] = []
     total = 0
     read = row_reader(arr)
     for idx, (lo, hi) in enumerate(plan):
@@ -458,13 +436,10 @@ def to_client(engine: AlchemistEngine, handle: MatrixHandle,
                                  dtype=arr.dtype)
         blocks[p][lo - pstarts[p]: hi - pstarts[p]] = block
         total += block.nbytes
-        sizes.append(block.nbytes)
         engine.transfer_log.record(
             block.nbytes, "to_client", session=sess,
-            chunk_index=idx, num_chunks=len(plan),
-            pipelined=(idx < len(plan) - 1))
-    rec = _aggregate_record(
-        engine.transfer_log, total, "to_client", sess, sizes)
+            chunk_index=idx, num_chunks=len(plan))
+    rec = _aggregate_record(total, "to_client", sess, len(plan))
     return RowMatrix.from_blocks(blocks), rec
 
 

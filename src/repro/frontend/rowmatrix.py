@@ -42,8 +42,8 @@ class RowMatrix:
         from the first partition, exactly like ``num_cols`` (``map_rows``
         may change the dtype, and must not run eagerly to reveal it).
         Tracked so byte-sized consumers — ``nbytes``, the transfer
-        layer's chunk sizing and cost models — never assume float64: a
-        float32 matrix is half the bytes."""
+        layer's chunk sizing — never assume float64: a float32 matrix is
+        half the bytes."""
         if self._dtype is None:
             self._derive_meta()
         return self._dtype

@@ -1,7 +1,7 @@
 """Blocked Gram matrix kernel: G = A^T A, A: (n, d).
 
 TPU adaptation of the paper's Gram hot spot (every Lanczos/CG step is built
-on A^T(A v); the explicit Gram path is used by gram_svd and benchmarks):
+on A^T(A v); the explicit Gram path is used by gram and gram_svd):
 rows are streamed HBM->VMEM in (bm, bn) tiles; each (i, j) output tile of
 size (bn, bn) accumulates partial A_ki^T A_kj products on the MXU in fp32.
 The k (row-chunk) grid axis is innermost so each output tile stays resident

@@ -260,13 +260,13 @@ def test_disconnect_invalidates_the_sessions_cached_results(engine):
 # =====================================================================
 # transfer dedup
 # =====================================================================
-def test_dedup_upload_zero_modeled_bytes(ac, engine):
+def test_dedup_upload_zero_bytes(ac, engine):
     x = RNG.randn(128, 32).astype(np.float32)
     a1 = ac.send_matrix(x)
     recs_before = len(engine.transfer_log.records)
     a2 = ac.send_matrix(x)
     rec = a2.last_transfer
-    assert rec.dedup and rec.nbytes == 0 and rec.modeled_socket_s == 0.0
+    assert rec.dedup and rec.nbytes == 0
     assert rec.logical_nbytes == x.nbytes
     # the dedup crossing is logged distinctly, as a single record
     assert len(engine.transfer_log.records) == recs_before + 1

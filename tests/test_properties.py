@@ -14,7 +14,6 @@ from hypothesis import given, settings, strategies as st  # noqa: E402
 
 from repro.common.config import ModelConfig
 from repro.core import AlchemistContext
-from repro.core.costmodel import socket_transfer_seconds
 from repro.core.libraries import elemental, skylark
 from repro.core.protocol import (
     Command,
@@ -73,17 +72,6 @@ def test_protocol_roundtrip_any_args(hid, r, c, name, session):
                                 "nest": {"k": [1, 2, h]}}, session=session)
     back = decode_command(encode_command(cmd))
     assert back == cmd
-
-
-@settings(max_examples=25, deadline=None)
-@given(nbytes=st.integers(1, 10**13), a=st.integers(1, 64),
-       b=st.integers(1, 64))
-def test_transfer_model_monotone(nbytes, a, b):
-    """More bytes never transfer faster; more (balanced) procs never slower."""
-    t = socket_transfer_seconds(nbytes, a, b)
-    assert t >= 0
-    assert socket_transfer_seconds(nbytes * 2, a, b) >= t
-    assert socket_transfer_seconds(nbytes, a + 1, b + 1) <= t + 1e-9
 
 
 @settings(max_examples=15, deadline=None)

@@ -244,7 +244,7 @@ def test_send_returns_aggregate_record(engine):
     _, rec = transfer.to_engine(engine, x, chunk_rows=33)
     assert rec.nbytes == x.nbytes
     assert rec.num_chunks == 4 and rec.chunk_index == -1
-    assert rec.modeled_socket_s > 0
+    assert rec.nbytes == sum(r.nbytes for r in engine.transfer_log.records)
 
 
 def test_rowmatrix_source_streams_without_collect(engine, monkeypatch):

@@ -127,9 +127,8 @@ def test_to_client_one_dim_handle(engine):
 # =====================================================================
 @pytest.mark.parametrize("direction", ["to_engine", "to_client"])
 def test_aggregate_matches_per_chunk_sum_with_runts(engine, direction):
-    """100 rows at chunk_rows=33 leaves a 1-row runt: the aggregate's
-    stream model must be built from the actual chunk list, not a mean
-    chunk size, so it equals the per-chunk records' sum exactly."""
+    """100 rows at chunk_rows=33 leaves a 1-row runt: the aggregate counts
+    the actual chunks and sums their bytes."""
     x = RNG.randn(100, 8)
     if direction == "to_engine":
         _, agg = transfer.to_engine(engine, x, chunk_rows=33)
@@ -147,17 +146,3 @@ def test_aggregate_matches_per_chunk_sum_with_runts(engine, direction):
         [33 * row_bytes] * 3 + [1 * row_bytes]
     assert agg.num_chunks == len(chunk_recs) == 4
     assert agg.nbytes == sum(r.nbytes for r in chunk_recs)
-    np.testing.assert_allclose(
-        agg.modeled_socket_s,
-        sum(r.modeled_socket_s for r in chunk_recs), rtol=1e-12)
-
-
-def test_uniform_chunks_agree_with_uniform_stream_model(engine):
-    """When chunks ARE uniform, the chunk-list model reduces to the
-    uniform-chunk stream model the Table-3 sweep uses."""
-    from repro.core.costmodel import (
-        stream_transfer_seconds, stream_transfer_seconds_from_chunks)
-    sizes = [1 << 20] * 8
-    np.testing.assert_allclose(
-        stream_transfer_seconds_from_chunks(sizes, 20, 20),
-        stream_transfer_seconds(8 << 20, 1 << 20, 20, 20), rtol=1e-12)
